@@ -7,9 +7,10 @@ Three claim families, landing in BENCH_era_step.json:
      fused pipeline ``era_step_value_and_grad`` — across problem sizes;
   2. roofline position of that step before/after fusion: FLOPs and the
      HBM-write proxy from the trip-count-aware HLO parser
-     (launch/hlo_cost.cost_of_callable), placed against the platform peaks
-     (launch/roofline.step_roofline).  The fused step's claim is fewer
-     materialised intermediates — write_bytes is the number to watch;
+     (launch/hlo_cost.cost_of_callable), placed against the running
+     device's peaks (launch/roofline.step_roofline) and skipped on a device
+     ``launch/platform.PEAKS`` has none for.  The fused step's claim is
+     fewer materialised intermediates — write_bytes is the number to watch;
   3. full-solve latency across the 1/2/4/8 cell bucket ladder under the
      sharded backend, ``step_impl='xla'`` vs ``'fused'``, plus the final-Γ
      relative agreement between the two paths (the regression bound
@@ -35,24 +36,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit
+from benchmarks.common import emit, emit_skip
 from repro.core import era, ligd, network, profiles
 from repro.core.era import Weights
 from repro.kernels.era_step import ops as eops
 from repro.kernels.era_step.kernel import (DEFAULT_VMEM_BUDGET,
                                            block_vmem_bytes, choose_block_m)
 from repro.launch.hlo_cost import cost_of_callable
+from repro.launch.platform import roofline_peaks
 from repro.launch.roofline import step_roofline, tiled_step_roofline
 
 PER_STEP_SIZES = [(8, 4), (16, 8), (32, 8), (64, 16)]  # (users, subchannels)
 BUCKETS = (1, 2, 4, 8)
 GD_CHUNK = 8
 PAPER_U, PAPER_M = 1250, 250
-# CPU lane of the paper-scale record: the auto-chosen TPU block (bm=1,
-# 250 grid steps) would unroll into a 250-block XLA loop here — use a
-# divisor that keeps per-block host buffers small (~bm·U²·4 B ≈ 312 MB
-# of masks) without exploding compile time
+# CPU lane of the paper-scale record: a small block would unroll into a
+# many-block XLA loop here — use a divisor that keeps per-block host
+# buffers small (~bm·U²·4 B ≈ 312 MB of masks) without exploding compile
+# time
 PAPER_BLOCK_M_CPU = 50
+NO_PEAKS = "no published peaks for this device kind (launch/platform.PEAKS)"
+
+
+def _device_peaks():
+    """Peaks of the running device, or None where ``PEAKS`` publishes none
+    (a CPU): the roofline rows are then skipped, since HLO counts of the
+    interpret-mode kernel placed against a chip's peaks describe no run."""
+    try:
+        return roofline_peaks()
+    except ValueError:
+        return None
 
 
 def _median_time(fn, n=5):
@@ -87,6 +100,7 @@ def _block(out):
 
 
 def _per_step(sizes, reps):
+    peaks = _device_peaks()
     for u, m in sizes:
         scn, prof, q, w, s_vec, alloc = _step_setup(u, m)
         aux = eops.build_aux(scn)
@@ -110,10 +124,14 @@ def _per_step(sizes, reps):
 
         # roofline: cost the compiled step bodies, place on the platform
         # roofline — the fused claim is the write_bytes (fusion) column
-        rx = step_roofline(cost_of_callable(jax.value_and_grad(loss), alloc))
+        if peaks is None:
+            emit_skip(f"era_step.roofline.{tag}", NO_PEAKS)
+            continue
+        rx = step_roofline(cost_of_callable(jax.value_and_grad(loss), alloc),
+                           peaks=peaks)
         rf = step_roofline(cost_of_callable(
             lambda a: eops.era_step_value_and_grad(
-                scn, prof, s_vec, q, a, w, aux=aux), alloc))
+                scn, prof, s_vec, q, a, w, aux=aux), alloc), peaks=peaks)
         for impl, r in (("xla", rx), ("fused", rf)):
             emit(f"era_step.roofline_{impl}.{tag}", 0.0,
                  f"flops={r['flops']:.3e} write_bytes={r['write_bytes']:.3e} "
@@ -175,11 +193,15 @@ def _paper_scale(reps):
     us_f = _median_time(lambda: _block(fused_fn(alloc)), reps)
     emit(f"era_step.paper.step_fused_us.{tag}", us_f, f"bm={bm_cpu}")
 
+    peaks = _device_peaks()
+    if peaks is None:
+        emit_skip(f"era_step.paper.roofline.{tag}", NO_PEAKS)
+        return
     rf = tiled_step_roofline(
         cost_of_callable(lambda a: eops.era_step_value_and_grad(
             scn, prof, s_vec, q, a, w, aux=aux, block_m=bm_cpu), alloc),
         n_blocks=-(-m // bm), block_vmem_bytes=vmem,
-        vmem_budget=DEFAULT_VMEM_BUDGET)
+        vmem_budget=DEFAULT_VMEM_BUDGET, peaks=peaks)
     emit(f"era_step.paper.roofline_fused.{tag}", 0.0,
          f"flops={rf['flops']:.3e} write_bytes={rf['write_bytes']:.3e} "
          f"intensity={rf['intensity']:.2f} bound={rf['bound']} "
@@ -192,7 +214,8 @@ def _paper_scale(reps):
     def loss(a):
         return era.utility(scn, prof, s_vec, a, q, w).gamma
 
-    rx = step_roofline(cost_of_callable(jax.value_and_grad(loss), alloc))
+    rx = step_roofline(cost_of_callable(jax.value_and_grad(loss), alloc),
+                       peaks=peaks)
     emit(f"era_step.paper.roofline_xla.{tag}", 0.0,
          f"flops={rx['flops']:.3e} write_bytes={rx['write_bytes']:.3e} "
          f"intensity={rx['intensity']:.2f} bound={rx['bound']} "

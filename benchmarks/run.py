@@ -109,6 +109,8 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import common
+    from repro.launch import platform
+    platform.enable_compile_cache()
 
     print("name,us_per_call,derived")
     t0 = time.time()

@@ -179,11 +179,13 @@ class SolverSpec:
       step_block_m    channel-tile size of the fused step's Pallas grid
                       (``kernels/era_step``): 0 (default) auto-sizes from
                       the kernel's VMEM budget — untiled whenever the
-                      whole problem fits, bm=1 at paper scale; > 0 forces
-                      that block on both the kernel and the jnp oracle
-                      (the oracle runs its tiled mirror, reproducing the
-                      kernel's accumulation order).  'fused' step_impl
-                      only; jit-static of the sweep.
+                      whole problem fits, paper scale included; > 0 is
+                      rounded to a tile Mosaic compiles (M, or a multiple
+                      of 8) and that block is forced on both the kernel
+                      and the jnp oracle (the oracle runs its tiled
+                      mirror, reproducing the kernel's accumulation
+                      order).  'fused' step_impl only; jit-static of the
+                      sweep.
     """
     backend: str = "reference"
     gd_chunk: int = 0
@@ -1061,10 +1063,15 @@ def solve_batch(scns, prof, q, w: Weights = Weights(), *,
     gammas = np.asarray(swept.gamma)                       # (B, F+1)
     iters = np.asarray(swept.iters)
     s_star = jnp.asarray(np.argmin(gammas, axis=1), jnp.int32)   # (B,)
-    cell_ix = jnp.arange(n_cells)
+    # select layer s* per cell by a one-hot sum, not a gather: under a
+    # mesh with Explicit axes the gather's output sharding is ambiguous
+    # and raises, while select-and-sum keeps x's cells sharding (and is
+    # exact: every other term is 0.0)
+    pick = jax.nn.one_hot(s_star, gammas.shape[1], dtype=bool)   # (B, F+1)
 
     def at_star(x):
-        return x[cell_ix, s_star]
+        mask = pick.reshape(pick.shape + (1,) * (x.ndim - 2))
+        return jnp.sum(jnp.where(mask, x, 0), axis=1)
 
     if spec.per_user_split:
         costs = _cost_table_batch(scn_b, q, swept.alloc, w, prof_b,

@@ -27,6 +27,10 @@ its solver regression tests pin rtol=1e-5.  Cost is O(U²·M) against the
 cumsum's O(U·M) — at test scale it is noise, and at paper scale the hot
 path is the fused kernel, where the mask matvec is an MXU dot.
 
+Every contraction here pins ``Precision.HIGHEST``: TPU's default f32
+matmul is one bf16 pass, which would round each interference term to 8
+mantissa bits and make the solver's answer depend on the platform.
+
 Batch-safety audit (ligd.solve_batch vmaps this module over a leading cell
 axis): every reduction here is over an explicit named axis (cumsum axis=1,
 rate sum axis=1, einsum subscripts, segment_sum over the per-cell ``assoc``)
@@ -54,7 +58,8 @@ def _suffix_interference(contrib_sorted, group_end):
     same = group_end[..., :, None] == group_end[..., None, :]
     later = idx[None, :] > idx[:, None]
     mask = (same & later).astype(contrib_sorted.dtype)
-    return jnp.einsum("...ij,...j->...i", mask, contrib_sorted)
+    return jnp.einsum("...ij,...j->...i", mask, contrib_sorted,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def uplink_sinr(scn, beta_up, p):
@@ -80,7 +85,7 @@ def uplink_sinr(scn, beta_up, p):
     other = 1.0 - jax.nn.one_hot(scn.assoc, cfg.n_aps,
                                  dtype=contrib.dtype)         # (U, N)
     t_other = jnp.einsum("um,unm,un->nm", beta_up * p[:, None], scn.h_up,
-                         other)
+                         other, precision=jax.lax.Precision.HIGHEST)
     inter = jnp.maximum(t_other, 0.0)[scn.assoc]   # (U, M)
 
     sig = p[:, None] * own
@@ -109,7 +114,8 @@ def downlink_sinr(scn, beta_dn, p_ap):
     ap_power = jax.ops.segment_sum(comp, scn.assoc,
                                    num_segments=cfg.n_aps)   # (N, M)
     other = 1.0 - jax.nn.one_hot(scn.assoc, cfg.n_aps, dtype=comp.dtype)
-    cross = jnp.einsum("nm,num,un->um", ap_power, scn.h_dn, other)
+    cross = jnp.einsum("nm,num,un->um", ap_power, scn.h_dn, other,
+                       precision=jax.lax.Precision.HIGHEST)
     inter = jnp.maximum(cross, 0.0)
 
     sig = p_ap[:, None] * own

@@ -107,10 +107,7 @@ def initialize_from_env() -> HostInfo:
     if n_procs > 1:
         # gloo must be selected before the CPU client exists; on other
         # platforms the option is inert (it only steers CPU collectives)
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — option absent on this jax
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=n_procs, process_id=pid)
     _INITIALIZED = True
@@ -146,8 +143,8 @@ def global_cells_mesh(n_devices: int = None):
     orders them grouped by process, giving each host a contiguous lane
     slice); a partial ``n_devices`` is rejected there, because a prefix
     mesh would leave some processes with no addressable shard of the
-    SPMD program.  Memoised like ``cells_mesh``, built through the
-    ``_make_mesh`` AxisType shim (0.4.x floor — see launch/mesh.py)."""
+    SPMD program.  Memoised like ``cells_mesh``, built with Auto axes
+    (``launch.mesh._make_mesh``)."""
     if jax.process_count() == 1:
         return solver_mesh.cells_mesh(n_devices)
     n_avail = len(jax.devices())

@@ -28,7 +28,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import ligd, network
@@ -47,8 +46,7 @@ def cells_mesh(n_devices: int = None):
     request uses a prefix of them.  Memoised per device count, so
     ``SolverSpec.run_mesh()``'s lazy all-devices default resolves to the
     identical Mesh object on every call and the sharded sweep's jit cache
-    never splinters.  Built through the ``_make_mesh`` AxisType shim
-    (0.4.x floor — see launch/mesh.py)."""
+    never splinters.  Built with Auto axes (``launch.mesh._make_mesh``)."""
     n_avail = len(jax.devices())
     n = n_avail if n_devices is None else max(1, min(n_devices, n_avail))
     mesh = _MESH_CACHE.get(n)
@@ -101,13 +99,14 @@ def _sharded_sweep_fn(mesh, max_steps, w, adaptive, gd_chunk, step_impl,
             step_block_m=step_block_m, prof_batched=prof_batched,
             x_init_batched=x_init_batched)
 
-    # check_rep=False: jax<=0.4 has no replication rule for `while`; every
-    # output is cell-sharded anyway, so replication tracking buys nothing
-    sharded = shard_map(
+    # check_vma=False: the GD loops seed their carries with unsharded
+    # constants (Γ=inf, done=False) that the body makes cell-varying; every
+    # output is cell-sharded anyway, so tracking replication buys nothing
+    sharded = jax.shard_map(
         local_sweep, mesh=mesh,
         in_specs=(cells, cells, cells if x_init_batched else repl, cells,
                   repl, repl, cells if prof_batched else repl),
-        out_specs=cells, check_rep=False)
+        out_specs=cells, check_vma=False)
     fn = jax.jit(sharded)
     _SWEEP_CACHE[key] = fn
     return fn

@@ -7,12 +7,13 @@ ops (plus their autodiff transposes).  This kernel evaluates the whole
 forward+backward in ONE launch with zero intermediate HBM traffic — a
 custom-VJP-style fusion over the user axis.  SIC suffix interference is a
 masked matvec (``ref._sic_mask``, the same cancellation-free formulation
-noma_rate and core.noma use), so the hot ops are MXU dots over
-in-register 0/1 masks; the backward is the transposed mask einsum
-(scatter- and gather-free, see ref.py).
+noma_rate and core.noma use), so the hot ops are MXU dots over 0/1
+masks built in VMEM; the backward contracts the same mask over its other
+index (scatter- and gather-free, see ref.py).
 
 The kernel body calls ref.py's four block helpers on its loaded slabs —
-the oracle and the kernel share one definition of the arithmetic, so the
+the oracle and the kernel share one definition of the arithmetic, all
+but the SIC contraction itself (see "SIC masks" below), so the
 kernel sweep (tests/test_era_step.py) validates Pallas plumbing and Mosaic
 lowering, while ref-vs-autodiff validates the math itself.
 
@@ -39,21 +40,24 @@ blocks:
            the row lives in VMEM across the whole grid, accumulated
            in-place, copied out once at grid end).
 
-The (bm, U, U) SIC mask blocks expand in VMEM from two (bm, U) rank/gid
-rows per link direction — the O(M·U²) mask is never materialised in HBM
-at ANY block size, which is the whole point: ``bm`` bounds the transient.
+SIC masks: inside a grid step the suffix operator runs one channel at a
+time (``_ChannelSIC``): each channel's (U, U) mask is built in VMEM from
+its two rank/gid rows and contracted on the MXU, so the O(M·U²) mask is
+never materialised in HBM, nor more than one channel of it in VMEM.
 
-Sizing: ``block_vmem_bytes`` estimates one grid step's resident set —
-the two mask blocks dominate at 2·bm·U²·4 B; blocked operands and live
-temporaries add ~(34 + 2N)·bm·U·4 B, plus O(U) rows.  ``choose_block_m``
-picks the largest divisor of M under ``DEFAULT_VMEM_BUDGET`` (14 MiB —
-headroom under the ~16 MiB/core budget), degenerating to the untiled
-``bm = M`` single-block launch whenever the whole problem fits (all test
-scales) and to ``bm = 1`` at the paper's U=1250/M=250 (~12.3 MiB/step).
-An explicit ``block_m`` that does not divide M zero-pads the M axis to
-the next multiple — padded channels carry zero gain/β/rank rows, which
-contribute exactly 0.0 to every cross-block sum (rates and gradients), so
-padding is bitwise-neutral; the padded β-gradient rows are sliced off.
+Sizing: ``block_vmem_bytes`` estimates one grid step's scoped VMEM —
+one (U, U) mask plus ~40 f32 (bm, U) rows per channel row, fit to
+Mosaic's own accounting.  ``choose_block_m`` picks ``bm = M`` (the
+untiled single-block launch) whenever that fits the 64 MiB the kernel
+requests (``DEFAULT_VMEM_BUDGET``) — every test scale and the paper's
+U=1250/M=250 (~58 MiB estimated, 25 MiB needed) — else the largest
+multiple of 8 dividing M rounded up to 8.  A block that does not divide M
+zero-pads the M axis to the next multiple — padded channels carry zero
+gain/β/rank rows, which contribute exactly 0.0 to every cross-block sum
+(rates and gradients), so padding is bitwise-neutral; the padded
+β-gradient rows are sliced off.  Compiled, a (bm, U) block must have
+``bm`` a multiple of 8 or equal to M (``legal_block_m``); interpret mode
+runs any block.
 
 Operands and gradients are all f32 with no data-dependent indexing at
 all, precisely so this lowers to Mosaic as dots + elementwise ops — the
@@ -76,59 +80,130 @@ from repro.kernels.era_step import ref as _ref
 from repro.kernels.era_step.ref import (
     BLOCKED_AXIS, N_OPERANDS, _BW, _NOISE)
 
-# VMEM budget choose_block_m sizes against: 14 MiB of the ~16 MiB/core,
-# leaving headroom for Mosaic's own spills and the double-buffered
-# operand windows.
-DEFAULT_VMEM_BUDGET = 14 * 1024 * 1024
+# Scoped VMEM the kernel requests from Mosaic (``vmem_limit_bytes``) and
+# that ``choose_block_m`` sizes a grid step against: half of the 128 MiB
+# a TPU v5e core has, the rest left to XLA, which may place the
+# kernel's operands in VMEM too.  (Unrequested, Mosaic scopes a kernel
+# to 16 MiB.)
+DEFAULT_VMEM_BUDGET = 64 * 1024 * 1024
+
+# Fit to Mosaic's own accounting at U=1250, N=5 (the smallest scoped
+# limit each block compiles under for a described v5e: 5 MiB at bm=8,
+# 15 at 64, 27 at 128): ~37.5 f32 (bm, U) rows per channel row — the
+# blocked operands and outputs with their second pipeline buffers, the
+# SIC row buffers and the helpers' live temporaries — over a fixed part
+# under one channel's (U, U) mask.  Both are rounded up, so the estimate
+# stays above the real need.
+_LIVE_ROWS = 40
+
+
+def _round_up(x, k):
+    return -(-x // k) * k
 
 
 def block_vmem_bytes(bm, u, n_aps):
-    """Estimated f32 VMEM resident set of ONE grid step at block size
-    ``bm``: the two (bm, U, U) SIC mask blocks, the blocked 2-D operand
-    slabs plus live per-direction temporaries (~34 rows of (bm, U)), the
-    two (N, bm, U) cross-gain slabs, and the O(U) scalar rows
-    (operands, outputs, scratch, one-hot, env)."""
-    masks = 2 * bm * u * u
-    rows_2d = (34 + 2 * n_aps) * bm * u
-    rows_1d = (24 + n_aps) * u + _ref.ENV_LANES
-    return 4 * (masks + rows_2d + rows_1d)
+    """Estimated scoped VMEM of ONE grid step at block size ``bm``, in
+    padded (8, 128) f32 tiles: one channel's (U, U) SIC mask (the channel
+    loop never holds two), ``_LIVE_ROWS`` (bm, U) rows, and the
+    double-buffered (1, U)/(N, U) rows, outputs and scratch."""
+    lanes = _round_up(u, 128)
+    mask = _round_up(u, 8) * lanes
+    live = _LIVE_ROWS * _round_up(bm, 8) * lanes
+    small = (2 * (10 + n_aps + 4) + 4) * 8 * lanes
+    return 4 * (mask + live + small)
+
+
+def legal_block_m(bm, m):
+    """The channel block Mosaic can tile: the last two dims of a (bm, U)
+    block must be M itself or divisible by 8 (the f32 sublane tile), so
+    round ``bm`` up to a multiple of 8, or to M when that reaches it."""
+    if bm >= m:
+        return m
+    bm = _round_up(bm, 8)
+    return m if bm >= m else bm
 
 
 def choose_block_m(m, u, n_aps, budget_bytes=DEFAULT_VMEM_BUDGET):
-    """Largest channel-block size whose grid step fits ``budget_bytes``:
+    """Largest legal channel block whose grid step fits ``budget_bytes``:
     ``m`` itself (the untiled single-block launch) when the whole problem
-    fits, else the largest divisor of ``m`` under budget (divisors avoid
-    the zero-pad remainder block; 1 always divides).  ``bm = 1`` is the
-    floor even if over budget — at that point U itself is the problem and
-    the caller should shard users, not channels."""
+    fits, else the largest multiple of 8 that divides ``m`` rounded up to
+    8 — so the zero-padded remainder is at most 7 channels.  8 is the
+    floor even if over budget: past that, U itself is the problem and the
+    caller should shard users, not channels."""
     if block_vmem_bytes(m, u, n_aps) <= budget_bytes:
         return m
-    best = 1
-    for bm in range(2, m):
-        if m % bm == 0 and block_vmem_bytes(bm, u, n_aps) <= budget_bytes:
+    m8 = _round_up(m, 8)
+    best = 8
+    for bm in range(16, m8, 8):
+        if m8 % bm == 0 and block_vmem_bytes(bm, u, n_aps) <= budget_bytes:
             best = bm
     return best
+
+
+class _ChannelSIC:
+    """The SIC suffix operator of one channel block, one channel at a
+    time: per channel, the (U, U) mask is built from that channel's rank
+    and group-id rows and contracted with its (1, U) row on the MXU.  The
+    same ``apply``/``transpose`` pair as ``ref.MaskSIC``, which builds the
+    whole (bm, U, U) mask — a block Mosaic neither lowers (its batched
+    matvec contracts the mask's middle axis in the adjoint) nor fits in
+    VMEM at paper scale (~50 MB per mask at bm=8, U=1250).  Both
+    contractions take the mask as the plain 2-D rhs: NT for ``apply``
+    (Σ_j mask[i, j]·x[j]), NN for ``transpose`` (Σ_i mask[i, j]·d[i]).
+
+    ``x_buf``/``out_buf`` are (bm, U) VMEM scratch rows: the operand is
+    parked there so the loop can read it one row at a time."""
+
+    def __init__(self, rank_ref, gid_ref, x_buf, out_buf):
+        self.rank_ref = rank_ref
+        self.gid_ref = gid_ref
+        self.x_buf = x_buf
+        self.out_buf = out_buf
+
+    def _contract(self, x, rhs_dim):
+        self.x_buf[...] = x
+
+        def body(c, carry):
+            rank = self.rank_ref[pl.ds(c, 1), :]           # (1, U)
+            gid = self.gid_ref[pl.ds(c, 1), :]
+            mask = ((gid.T == gid) & (rank > rank.T)).astype(jnp.float32)
+            self.out_buf[pl.ds(c, 1), :] = jax.lax.dot_general(
+                self.x_buf[pl.ds(c, 1), :], mask,
+                (((1,), (rhs_dim,)), ((), ())),
+                precision=_ref.HIGHEST, preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, self.x_buf.shape[0], body, 0)
+        return self.out_buf[...]
+
+    def apply(self, x):
+        return self._contract(x, 1)
+
+    def transpose(self, d):
+        return self._contract(d, 0)
 
 
 def _kernel(*refs):
     ins = refs[:N_OPERANDS]
     (gamma_ref, dbu_ref, dbd_ref, dp_ref, dpap_ref,
      dr_ref) = refs[N_OPERANDS:N_OPERANDS + 6]
-    rup_acc, rdn_acc, grup, grdn = refs[N_OPERANDS + 6:]
+    rup_acc, rdn_acc, grup, grdn, x_buf, out_buf = refs[N_OPERANDS + 6:]
     phase = pl.program_id(0)
     b = pl.program_id(1)
     envp = ins[10][...]
     noise = envp[0, _NOISE]
     bw = envp[0, _BW]
+    up_sic = _ChannelSIC(ins[16], ins[17], x_buf, out_buf)
+    dn_sic = _ChannelSIC(ins[18], ins[19], x_buf, out_buf)
 
     def up_args():
-        # (beta_up_t, p, own_up_t, h_up_r, onehot, up_rank, up_gid)
+        # (beta_up_t, p, own_up_t, h_up_r, onehot, sic)
         return (ins[0][...], ins[2][...], ins[11][...], ins[13][...],
-                ins[15][...], ins[16][...], ins[17][...])
+                ins[15][...], up_sic)
 
     def dn_args():
         return (ins[1][...], ins[3][...], ins[12][...], ins[14][...],
-                ins[15][...], ins[18][...], ins[19][...])
+                ins[15][...], dn_sic)
 
     @pl.when((phase == 0) & (b == 0))
     def _init():
@@ -154,7 +229,8 @@ def _kernel(*refs):
             rup_acc[...], rdn_acc[...], ins[2][...], ins[3][...],
             ins[4][...], ins[5][...], ins[6][...], ins[7][...],
             ins[8][...], ins[9][...], envp)
-        gamma_ref[0, 0] = gamma
+        # a full-block store: Mosaic stores no scalar to VMEM
+        gamma_ref[...] = jnp.full((1, 1), gamma, jnp.float32)
         dr_ref[...] = d_r
         dp_ref[...] += d_p0
         dpap_ref[...] += d_pap0
@@ -188,7 +264,9 @@ def era_step_fused(*operands, block_m=0, interpret=False):
     m, u = operands[0].shape
     n_aps = operands[15].shape[0]
     bm = block_m if block_m > 0 else choose_block_m(m, u, n_aps)
-    bm = min(bm, m)
+    # interpret mode runs any block (the tests sweep odd ones); Mosaic
+    # tiles only legal ones
+    bm = min(bm, m) if interpret else legal_block_m(bm, m)
     nb = -(-m // bm)
     m_pad = nb * bm
     if m_pad != m:
@@ -234,9 +312,11 @@ def era_step_fused(*operands, block_m=0, interpret=False):
         in_specs=[in_spec(i, x) for i, x in enumerate(operands)],
         out_specs=out_specs,
         out_shape=out_shapes,
-        scratch_shapes=[pltpu.VMEM((1, u), jnp.float32)] * 4,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+        scratch_shapes=([pltpu.VMEM((1, u), jnp.float32)] * 4
+                        + [pltpu.VMEM((bm, u), jnp.float32)] * 2),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=DEFAULT_VMEM_BUDGET),
         interpret=interpret,
     )(*operands)
     if m_pad != m:

@@ -69,8 +69,11 @@ def _rank_gid(order, group_end):
     u = order.shape[-1]
     oh = jax.nn.one_hot(order.astype(jnp.int32), u, dtype=jnp.float32)
     gs = _group_starts(group_end.astype(jnp.int32)).astype(jnp.float32)
-    rank = jnp.einsum("k,mki->mi", jnp.arange(u, dtype=jnp.float32), oh)
-    gid = jnp.einsum("mki,mk->mi", oh, gs)
+    # HIGHEST keeps the relabelled integers exact: TPU's default one-pass
+    # bf16 contraction would round every rank and group id above 256
+    rank = jnp.einsum("k,mki->mi", jnp.arange(u, dtype=jnp.float32), oh,
+                      precision=_ref.HIGHEST)
+    gid = jnp.einsum("mki,mk->mi", oh, gs, precision=_ref.HIGHEST)
     return rank, gid
 
 
@@ -140,13 +143,17 @@ def era_step_value_and_grad(scn, prof, s_vec, q, alloc, w, *, aux=None,
     the same fused arithmetic via the oracle.  ``interpret`` defaults to
     True off-TPU (kernel impl only).  ``block_m``: channel-tile size —
     0 (default) lets the kernel auto-size from its VMEM budget
-    (``kernel.choose_block_m``; the ref oracle stays untiled), > 0 forces
-    that block on both impls (the ref runs its tiled mirror, so CPU
-    backends reproduce the kernel's accumulation order exactly).  Pass a
-    precomputed ``aux`` (``build_aux``) when calling repeatedly on one
-    scenario."""
+    (``kernel.choose_block_m``; the ref oracle stays untiled), > 0 is
+    first rounded to the tile Mosaic can compile (``kernel.legal_block_m``:
+    M, or a multiple of 8) and that block is forced on both impls (the ref
+    runs its tiled mirror, so CPU backends reproduce the compiled kernel's
+    accumulation order).  Pass a precomputed ``aux`` (``build_aux``) when
+    calling repeatedly on one scenario."""
+    from repro.kernels.era_step.kernel import era_step_fused, legal_block_m
     if impl is None:
         impl = "kernel" if jax.default_backend() == "tpu" else "ref"
+    if block_m > 0:
+        block_m = legal_block_m(block_m, alloc.beta_up.shape[1])
     if aux is None:
         aux = build_aux(scn)
     operands = _operands(scn, prof, s_vec, q, alloc, aux, w)
@@ -154,7 +161,6 @@ def era_step_value_and_grad(scn, prof, s_vec, q, alloc, w, *, aux=None,
         gamma, grads = _ref.era_step_ref(
             *operands, block_m=block_m if block_m > 0 else None)
     elif impl == "kernel":
-        from repro.kernels.era_step.kernel import era_step_fused
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
         gamma, *grads = era_step_fused(*operands, block_m=block_m,

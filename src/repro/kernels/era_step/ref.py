@@ -46,17 +46,20 @@ tiled-vs-untiled differs only by f32 accumulation order.
 SIC suffix interference as a masked matvec: user i's intra-cell
 interference is the sum over same-SIC-group users decoded after i —
 ``mask[i, j] = [gid_i == gid_j] · [rank_j > rank_i]`` applied to the
-per-user contributions (one einsum per link direction).  The (bm, U, U)
-mask is built in-registers from two (bm, U) aux rows (decode rank + group
-id) — never an HBM operand, and at paper scale never materialised whole;
-its adjoint is the SAME mask einsum with the index order swapped, so the
-backward is transpose-free and gather-free by construction.  This
-deliberately avoids the sorted-cumsum-difference form noma.py used to use:
-  * no in-loop ``take_along_axis`` — XLA:CPU's SPMD partitioner
-    miscompiles per-lane dynamic gathers inside a ``while_loop`` under
-    fully-partitioned ``shard_map`` (wrong/stale permutation on non-zero
-    shards, observed on jax 0.4.37; masks and matmuls are unaffected),
-    and the solver's sharded backend runs exactly that composition;
+per-user contributions (one contraction per link direction).  The mask is
+built from two (bm, U) aux rows (decode rank + group id) — never an HBM
+operand; its adjoint is the SAME mask contracted over the other index, so
+the backward is gather-free by construction.  The block helpers take the
+operator as a ``sic`` argument: the oracle passes ``MaskSIC`` (the whole
+(bm, U, U) mask, one einsum), the kernel its one-channel-at-a-time form,
+so at paper scale the kernel never holds more than one (U, U) mask.  Both
+contract at ``Precision.HIGHEST``.  This deliberately avoids the
+sorted-cumsum-difference form noma.py used to use:
+  * no in-loop ``take_along_axis`` — XLA:CPU's SPMD partitioner has
+    miscompiled per-lane dynamic gathers inside a ``while_loop`` under
+    fully-partitioned ``shard_map`` (wrong permutation on non-zero
+    shards), and the solver's sharded backend runs exactly that
+    composition;
   * no large-prefix cancellation — the mask sums only in-group terms,
     where the global cumsum difference loses ~3 decimal digits in f32
     across the path-loss dynamic range;
@@ -78,6 +81,10 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+# f32 contractions everywhere: TPU's default f32 matmul precision is one
+# bf16 pass, which would round every interference term to 8 mantissa bits
+HIGHEST = jax.lax.Precision.HIGHEST
 
 _LN2 = 0.6931471805599453
 
@@ -108,7 +115,7 @@ def _sic_mask(rank, gid):
 def _suffix_apply(mask, x):
     """``out[m, i] = Σ_j mask[m, i, j] · x[m, j]`` — the in-group
     decoded-after suffix sum in user order."""
-    return jnp.einsum("mij,mj->mi", mask, x)
+    return jnp.einsum("mij,mj->mi", mask, x, precision=HIGHEST)
 
 
 def _suffix_transpose(mask, d):
@@ -116,7 +123,24 @@ def _suffix_transpose(mask, d):
     summed over the OTHER index — ``out[m, j] = Σ_i mask[m, i, j]·d[m, i]``
     (each user j's contribution interferes with every same-group user
     decoded before j)."""
-    return jnp.einsum("mij,mi->mj", mask, d)
+    return jnp.einsum("mij,mi->mj", mask, d, precision=HIGHEST)
+
+
+class MaskSIC:
+    """The SIC suffix operator of one channel block, as the oracle forms
+    it: the whole (bm, U, U) mask at once.  The block helpers below take
+    any object with this ``apply``/``transpose`` pair, so the kernel can
+    substitute its one-channel-at-a-time form (kernel.py) while every
+    other line of arithmetic stays shared."""
+
+    def __init__(self, rank, gid):
+        self.mask = _sic_mask(rank, gid)
+
+    def apply(self, x):
+        return _suffix_apply(self.mask, x)
+
+    def transpose(self, d):
+        return _suffix_transpose(self.mask, d)
 
 
 class _UpFwd(NamedTuple):
@@ -137,15 +161,13 @@ class _DnFwd(NamedTuple):
     rate_dn: jnp.ndarray
 
 
-def _up_forward(beta_up_t, p, own_up_t, h_up_r, onehot, up_rank, up_gid,
-                noise, bw):
+def _up_forward(beta_up_t, p, own_up_t, h_up_r, onehot, sic, noise, bw):
     """One channel block's uplink SIC pipeline (noma.uplink_sinr)."""
     n_aps = onehot.shape[0]
-    up_mask = _sic_mask(up_rank, up_gid)
     bp_u = beta_up_t * p                          # (bm, U) β·p
     contrib_u = bp_u * own_up_t                   # β·p·|h|²
     sig_u = p * own_up_t
-    intra_u = _suffix_apply(up_mask, contrib_u)
+    intra_u = sic.apply(contrib_u)
     # inter-cell residual at AP n summed cancellation-free over OTHER-cell
     # users (1 - onehot), not as t_all - own_cell: when no cross terms
     # exist the sum is exactly 0.0, hitting the same relu tie the autodiff
@@ -164,14 +186,12 @@ def _up_forward(beta_up_t, p, own_up_t, h_up_r, onehot, up_rank, up_gid,
     return _UpFwd(intra_u, tuple(raw_up), d_up, sinr_up, rate_up)
 
 
-def _dn_forward(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, dn_rank, dn_gid,
-                noise, bw):
+def _dn_forward(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, sic, noise, bw):
     """One channel block's downlink SIC pipeline (noma.downlink_sinr)."""
     n_aps = onehot.shape[0]
-    dn_mask = _sic_mask(dn_rank, dn_gid)
     comp_u = beta_dn_t * p_ap
     sig_d = p_ap * own_dn_t
-    intra_pwr_u = _suffix_apply(dn_mask, comp_u)
+    intra_pwr_u = sic.apply(comp_u)
     intra_d = intra_pwr_u * own_dn_t
     # same cancellation-free shape downlink: other-AP power only, never
     # cross_total - own_ap (see the uplink note above)
@@ -187,20 +207,20 @@ def _dn_forward(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, dn_rank, dn_gid,
     return _DnFwd(intra_d, raw_dn, d_dn, sinr_dn, rate_dn)
 
 
-def up_rate_rows(beta_up_t, p, own_up_t, h_up_r, onehot, up_rank, up_gid,
-                 noise, bw):
+def up_rate_rows(beta_up_t, p, own_up_t, h_up_r, onehot, sic, noise, bw):
     """Pass 1, uplink: this block's partial ``(1, U)`` rate row Σ_m β·rate
-    — the ONLY uplink quantity that crosses blocks."""
-    fwd = _up_forward(beta_up_t, p, own_up_t, h_up_r, onehot,
-                      up_rank, up_gid, noise, bw)
+    — the ONLY uplink quantity that crosses blocks.  ``sic``: the block's
+    SIC suffix operator (``MaskSIC`` or the kernel's per-channel form)."""
+    fwd = _up_forward(beta_up_t, p, own_up_t, h_up_r, onehot, sic, noise,
+                      bw)
     return jnp.sum(beta_up_t * fwd.rate_up, axis=0, keepdims=True)
 
 
-def dn_rate_rows(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, dn_rank, dn_gid,
-                 noise, bw):
+def dn_rate_rows(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, sic, noise,
+                 bw):
     """Pass 1, downlink partial rate row."""
-    fwd = _dn_forward(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot,
-                      dn_rank, dn_gid, noise, bw)
+    fwd = _dn_forward(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, sic, noise,
+                      bw)
     return jnp.sum(beta_dn_t * fwd.rate_dn, axis=0, keepdims=True)
 
 
@@ -254,19 +274,18 @@ def tail_grads(r_up, r_dn, p, p_ap, r, q, dev_fl, edge_fl, wup, wdn, envp):
     return gamma, g_rup, g_rdn, d_p0, d_pap0, d_r
 
 
-def up_block_grad(beta_up_t, p, own_up_t, h_up_r, onehot, up_rank, up_gid,
-                  noise, bw, g_rup):
+def up_block_grad(beta_up_t, p, own_up_t, h_up_r, onehot, sic, noise, bw,
+                  g_rup):
     """Pass 2, uplink: this block's ``(bm, U)`` β gradient rows and its
     partial ``(1, U)`` contribution to ``d_p``, given the tail's rate-row
     cotangent.  Recomputes the block forward (see module docstring)."""
     n_aps = onehot.shape[0]
-    up_mask = _sic_mask(up_rank, up_gid)
-    fwd = _up_forward(beta_up_t, p, own_up_t, h_up_r, onehot,
-                      up_rank, up_gid, noise, bw)
+    fwd = _up_forward(beta_up_t, p, own_up_t, h_up_r, onehot, sic, noise,
+                      bw)
     d_sinr = (g_rup * beta_up_t) * bw / ((1.0 + fwd.sinr_up) * _LN2)
     d_bu = g_rup * fwd.rate_up                    # direct Σ_m β·rate term
     psi = -d_sinr * fwd.sinr_up / fwd.d_up        # cotangent of D
-    d_contrib = _suffix_transpose(up_mask, psi * _tie(fwd.intra_u))
+    d_contrib = sic.transpose(psi * _tie(fwd.intra_u))
     d_bp = jnp.zeros_like(beta_up_t)
     for n in range(n_aps):
         g_n = jnp.sum(psi * onehot[n][None, :], axis=1,
@@ -279,19 +298,17 @@ def up_block_grad(beta_up_t, p, own_up_t, h_up_r, onehot, up_rank, up_gid,
     return d_bu, d_p_part
 
 
-def dn_block_grad(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, dn_rank,
-                  dn_gid, noise, bw, g_rdn):
+def dn_block_grad(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, sic, noise, bw,
+                  g_rdn):
     """Pass 2, downlink block gradient + partial ``d_pap`` row."""
     n_aps = onehot.shape[0]
-    dn_mask = _sic_mask(dn_rank, dn_gid)
-    fwd = _dn_forward(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot,
-                      dn_rank, dn_gid, noise, bw)
+    fwd = _dn_forward(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, sic, noise,
+                      bw)
     d_sinr_d = (g_rdn * beta_dn_t) * bw / ((1.0 + fwd.sinr_dn) * _LN2)
     d_bd = g_rdn * fwd.rate_dn
     psi_d = -d_sinr_d * fwd.sinr_dn / fwd.d_dn
     d_inter = psi_d * _tie(fwd.raw_dn)
-    d_comp = _suffix_transpose(dn_mask,
-                               psi_d * _tie(fwd.intra_d) * own_dn_t)
+    d_comp = sic.transpose(psi_d * _tie(fwd.intra_d) * own_dn_t)
     for n in range(n_aps):
         d_ap_n = jnp.sum(d_inter * h_dn_r[n]
                          * (1.0 - onehot[n][None, :]),
@@ -315,17 +332,18 @@ def fused_step_math(beta_up_t, beta_dn_t, p, p_ap, r, q,
     gradients in the same layouts as their primal operands."""
     noise = envp[0, _NOISE]
     bw = envp[0, _BW]
-    r_up = up_rate_rows(beta_up_t, p, own_up_t, h_up_r, onehot,
-                        up_rank, up_gid, noise, bw)
-    r_dn = dn_rate_rows(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot,
-                        dn_rank, dn_gid, noise, bw)
+    up_sic = MaskSIC(up_rank, up_gid)
+    dn_sic = MaskSIC(dn_rank, dn_gid)
+    r_up = up_rate_rows(beta_up_t, p, own_up_t, h_up_r, onehot, up_sic,
+                        noise, bw)
+    r_dn = dn_rate_rows(beta_dn_t, p_ap, own_dn_t, h_dn_r, onehot, dn_sic,
+                        noise, bw)
     gamma, g_rup, g_rdn, d_p, d_pap, d_r = tail_grads(
         r_up, r_dn, p, p_ap, r, q, dev_fl, edge_fl, wup, wdn, envp)
     d_bu, d_p_part = up_block_grad(beta_up_t, p, own_up_t, h_up_r, onehot,
-                                   up_rank, up_gid, noise, bw, g_rup)
+                                   up_sic, noise, bw, g_rup)
     d_bd, d_pap_part = dn_block_grad(beta_dn_t, p_ap, own_dn_t, h_dn_r,
-                                     onehot, dn_rank, dn_gid, noise, bw,
-                                     g_rdn)
+                                     onehot, dn_sic, noise, bw, g_rdn)
     return gamma, (d_bu, d_bd, d_p + d_p_part, d_pap + d_pap_part, d_r)
 
 
@@ -378,10 +396,12 @@ def era_step_ref(*operands, block_m=None):
     blocks = [_slice_block(operands, lo, hi) for lo, hi in spans]
 
     def up_args(blk):
-        return (blk[0], blk[2], blk[11], blk[13], blk[15], blk[16], blk[17])
+        return (blk[0], blk[2], blk[11], blk[13], blk[15],
+                MaskSIC(blk[16], blk[17]))
 
     def dn_args(blk):
-        return (blk[1], blk[3], blk[12], blk[14], blk[15], blk[18], blk[19])
+        return (blk[1], blk[3], blk[12], blk[14], blk[15],
+                MaskSIC(blk[18], blk[19]))
 
     # pass 1: accumulate the (1, U) rate rows block by block, in grid order
     u = operands[2].shape[1]

@@ -114,7 +114,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0, scale=None,
             pltpu.VMEM((bq_,), jnp.float32),      # running sum l
             pltpu.VMEM((bq_, d), jnp.float32),    # output accumulator
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

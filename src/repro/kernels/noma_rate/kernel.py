@@ -55,7 +55,7 @@ def noma_rate(contrib, sig, group_end, inter, *, bw, bm=8, interpret=False):
         in_specs=[pl.BlockSpec((bm, u), lambda i: (i, 0))] * 4,
         out_specs=pl.BlockSpec((bm, u), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, u), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(contrib, sig, group_end, inter)

@@ -54,7 +54,7 @@ def rglru_scan(a, b, *, lc=256, bd=256, interpret=False):
         out_specs=pl.BlockSpec((1, lc, bd), lambda ib, id_, il: (ib, il, id_)),
         out_shape=jax.ShapeDtypeStruct((bt, l, d), a.dtype),
         scratch_shapes=[pltpu.VMEM((bd,), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -100,7 +100,7 @@ def ssd_bhcqp(x, dt, a, b, c, d, *, chunk, interpret=False):
             jax.ShapeDtypeStruct((bt, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a, b, c, d)

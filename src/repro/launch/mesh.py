@@ -8,18 +8,15 @@ from __future__ import annotations
 
 import jax
 
+from repro.launch.platform import PEAKS
+
 
 def _make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API exists.
-    ``jax.sharding.AxisType`` arrived in JAX 0.5; on older runtimes (the
-    pinned 0.4.37 toolchain) every axis is implicitly Auto, so omitting
-    ``axis_types`` builds the identical mesh — the kwarg only matters for
-    Explicit/Manual axes, which nothing here uses."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axes: the solver's and the model's
+    shardings are propagated, not typed (the default axis type is
+    Explicit)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -36,7 +33,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     return _make_mesh((data, model), ("data", "model"))
 
 
-# TPU v5e roofline constants (single chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # bytes/s
+# TPU v5e roofline constants (single chip), from launch/platform.PEAKS
+PEAK_FLOPS_BF16 = PEAKS["TPU v5 lite"]["peak_flops"]     # FLOP/s
+HBM_BW = PEAKS["TPU v5 lite"]["mem_bw"]                  # bytes/s
 ICI_BW = 50e9                   # bytes/s effective per link

@@ -137,17 +137,45 @@ def describe() -> Dict:
     }
 
 
-def roofline_peaks() -> Dict[str, float]:
-    """Per-platform peak FLOP/s and memory bandwidth for roofline ratios.
-    TPU numbers are the v5e constants launch/mesh.py pins; CPU/GPU numbers
-    are order-of-magnitude class figures — good enough to CLASSIFY a
-    kernel as compute- vs memory-bound, not to predict its runtime."""
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` — a fixed path, since the path is part of
+    what a later process must find again.  Entry points call this from
+    ``main``; importing this module sets nothing."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
     import jax
-    platform = jax.devices()[0].platform
-    if platform == "tpu":
-        from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
-        return {"peak_flops": PEAK_FLOPS_BF16, "mem_bw": HBM_BW,
-                "basis": "tpu-v5e"}
-    if platform == "gpu":
-        return {"peak_flops": 60e12, "mem_bw": 1.5e12, "basis": "gpu-class"}
-    return {"peak_flops": 5e11, "mem_bw": 5e10, "basis": "cpu-class"}
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"peak_flops": 197e12, "mem_bw": 819e9},
+}
+
+
+def roofline_peaks(device_kind: Optional[str] = None) -> Dict[str, float]:
+    """Peak FLOP/s and memory bandwidth of ``device_kind`` (default: the
+    first visible device's) for roofline ratios.  A kind missing from
+    ``PEAKS`` is an error: a roofline against another chip's peaks — or
+    against a guess for a CPU — would be a number with no meaning."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    peaks = PEAKS.get(device_kind)
+    if peaks is None:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; have {sorted(PEAKS)}")
+    return dict(peaks, basis=device_kind)

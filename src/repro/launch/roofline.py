@@ -147,8 +147,7 @@ def tiled_step_roofline(cost, *, n_blocks=1, block_vmem_bytes=None,
     columns answer the orthogonal question: how many M-blocks does the
     launch sweep, and does ONE block's VMEM working set (masks + slabs,
     ``kernels/era_step/kernel.block_vmem_bytes``) fit the budget.  This is
-    the paper-scale audit: at (U=1250, M=250) the untiled launch is ~50×
-    over any VMEM budget; the tiled grid's fit lands here as data."""
+    the paper-scale audit: the chosen block's fit lands here as data."""
     row = step_roofline(cost, peaks=peaks)
     row["n_blocks"] = int(n_blocks)
     if block_vmem_bytes is not None:

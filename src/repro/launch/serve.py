@@ -126,6 +126,9 @@ def main():
                          "schedule carry-over + version continuity")
     args = ap.parse_args()
 
+    from repro.launch import platform
+    platform.enable_compile_cache()
+
     if args.backend == "multihost":
         # must precede ANY jax device-state touch (model init below)
         from repro.distributed import multihost

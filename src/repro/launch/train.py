@@ -30,6 +30,9 @@ def main():
     ap.add_argument("--dry-run", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch import platform
+    platform.enable_compile_cache()
+
     if args.dry_run:
         # defer to the dry-run module (sets XLA device-count flags itself)
         import subprocess

@@ -179,13 +179,23 @@ def prefill(params, cfg, x, positions, max_seq, mixer="attn", impl="naive",
 
     size = min(max_seq, cfg.window) if mixer == "local" else max_seq
     n_keep = min(s, size)
-    p0 = s - n_keep + jnp.arange(n_keep)          # absolute positions kept
-    slots = p0 % size
+    p0 = s - n_keep + jnp.arange(n_keep, dtype=jnp.int32)   # positions kept
+    # position p lives in ring slot p % size, so the kept run fills slots
+    # start, start+1, ... (mod size) — all static: write it at slot 0 and
+    # roll, rather than scatter (XLA:TPU's scatter fusion aborts on the
+    # scatter form inside the prefill scan)
+    start = (s - n_keep) % size
+
+    def ring(buf, new, axis):
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, new.astype(buf.dtype), 0, axis)
+        return jnp.roll(buf, start, axis)
+
     cache = init_cache(cfg, b, max_seq, mixer=mixer, dtype=k.dtype)
     cache = {
-        "k": cache["k"].at[:, slots].set(k[:, -n_keep:]),
-        "v": cache["v"].at[:, slots].set(v[:, -n_keep:]),
-        "pos": cache["pos"].at[slots].set(p0.astype(jnp.int32)),
+        "k": ring(cache["k"], k[:, -n_keep:], 1),
+        "v": ring(cache["v"], v[:, -n_keep:], 1),
+        "pos": ring(cache["pos"], p0, 0),
     }
     return y, cache
 

@@ -21,8 +21,7 @@ from repro.launch.steps import init_train_state, make_train_step
 from repro.training import optim
 
 cfg = get_tiny_config("{arch}").replace(dtype="float32", d_model=256, d_ff=512)
-# _make_mesh: Auto axis_types where jax.sharding.AxisType exists (JAX>=0.5),
-# plain make_mesh on the pinned 0.4.x toolchain (all axes implicitly Auto)
+# _make_mesh: Auto axis types (jax.make_mesh defaults to Explicit)
 mesh = _make_mesh((2, 4), ("data", "model"))
 rules = ShardingRules(cfg, mesh, mode="train")
 

@@ -28,7 +28,8 @@ from repro.core.era import Weights
 from repro.kernels.era_step import ops as eops
 from repro.kernels.era_step import ref as eref
 from repro.kernels.era_step.kernel import (
-    DEFAULT_VMEM_BUDGET, block_vmem_bytes, choose_block_m, era_step_fused)
+    DEFAULT_VMEM_BUDGET, block_vmem_bytes, choose_block_m, era_step_fused,
+    legal_block_m)
 
 pytestmark = pytest.mark.kernels
 
@@ -159,19 +160,30 @@ def test_tiled_kernel_matches_untiled_ref(bm, interpret):
 
 
 def test_choose_block_m_budget():
-    """Auto-sizing: untiled whenever the whole problem fits the VMEM
-    budget (every test scale), the largest divisor of M under budget
-    otherwise, and under-budget per block at the paper's U=1250/M=250."""
+    """Auto-sizing: untiled whenever the whole problem fits the VMEM the
+    kernel requests (every test scale, and the paper's U=1250/M=250 now
+    that the kernel holds one channel's mask at a time), else the largest
+    legal tile under budget — a multiple of 8 (the f32 sublane tile)
+    dividing M rounded up to 8, so at most 7 channels are zero-padded."""
     assert choose_block_m(6, 12, 2) == 6          # test scale: untiled
     assert choose_block_m(16, 64, 4) == 16
-    bm = choose_block_m(250, 1250, 5)
-    assert 250 % bm == 0 and bm < 250
-    assert block_vmem_bytes(bm, 1250, 5) <= DEFAULT_VMEM_BUDGET
-    # the O(M·U²) mask is the point of tiling: whole-problem residency
-    # would blow the budget by orders of magnitude
-    assert block_vmem_bytes(250, 1250, 5) > 50 * DEFAULT_VMEM_BUDGET
+    assert choose_block_m(250, 1250, 5) == 250    # paper scale: untiled
+    assert block_vmem_bytes(250, 1250, 5) <= DEFAULT_VMEM_BUDGET
+    for m, u in ((500, 1250), (250, 2500), (1000, 1250)):
+        bm = choose_block_m(m, u, 5)
+        m8 = -(-m // 8) * 8
+        assert bm < m and bm % 8 == 0 and m8 % bm == 0, (m, u, bm)
+        assert block_vmem_bytes(bm, u, 5) <= DEFAULT_VMEM_BUDGET
+        # maximal: the next legal divisor up is over budget
+        bigger = [b for b in range(bm + 8, m8, 8) if m8 % b == 0]
+        if bigger:
+            assert block_vmem_bytes(bigger[0], u, 5) > DEFAULT_VMEM_BUDGET
     # monotone: block estimate grows with bm, so the chosen bm is maximal
-    assert block_vmem_bytes(bm, 1250, 5) < block_vmem_bytes(2 * bm, 1250, 5)
+    assert block_vmem_bytes(64, 1250, 5) < block_vmem_bytes(128, 1250, 5)
+    # compiled blocks are legal: M itself or a multiple of 8
+    assert [legal_block_m(b, 6) for b in (1, 4, 6, 9)] == [6, 6, 6, 6]
+    assert [legal_block_m(b, 250) for b in (1, 8, 60, 64, 249, 300)] == \
+        [8, 8, 64, 64, 250, 250]
 
 
 def test_weight_sweep_shares_one_compile():
@@ -310,17 +322,39 @@ def test_fused_solve_tiled_matches_untiled():
     """step_block_m tiles the fused step under a full solve: forcing a
     block (including one that does not divide M) must leave the solve's
     outcome at the untiled fused path's answer — the cross-block
-    reductions are plain f32 sums, so only roundoff-order differs."""
-    scn, prof, q, w, _, _ = _setup(seed=4)         # m=6
+    reductions are plain f32 sums, so only roundoff-order differs.
+    M=24 so both blocks are legal compiled tiles (multiples of 8)."""
+    scn, prof, q, w, _, _ = _setup(m=24, seed=4)
     base = ligd.SolverSpec(tol=0.0, max_steps=40, step_impl="fused")
     o0 = ligd.solve(scn, prof, q, w, spec=base)
-    for bm in (2, 4):                              # divisible + remainder
+    for bm in (8, 16):                             # divisible + remainder
         ot = ligd.solve(scn, prof, q, w,
                         spec=base.replace(step_block_m=bm))
         np.testing.assert_allclose(ot.gamma_by_layer, o0.gamma_by_layer,
                                    rtol=1e-5)
         np.testing.assert_array_equal(np.asarray(ot.s), np.asarray(o0.s))
         _assert_alloc_close(ot.alloc, o0.alloc, 1e-5)
+
+
+@pytest.mark.parametrize("impl", ["ref", "kernel"])
+def test_ops_rounds_block_to_legal_tile(impl):
+    """The program's step entry rounds a forced block to the tile Mosaic
+    compiles (kernel.legal_block_m) before either impl sees it, so a
+    ``step_block_m`` tiles alike on CPU and TPU: 3 runs as 8, and a
+    block past M as M — bitwise the same launch."""
+    scn, prof, q, w, s_vec, alloc = _setup(u=12, m=24, seed=9)
+    aux = eops.build_aux(scn)
+
+    def step(bm):
+        return eops.era_step_value_and_grad(scn, prof, s_vec, q, alloc, w,
+                                            aux=aux, impl=impl,
+                                            interpret=True, block_m=bm)
+    for forced, legal in ((3, 8), (30, 24)):
+        g_f, grad_f = step(forced)
+        g_l, grad_l = step(legal)
+        assert float(g_f) == float(g_l)
+        for a, b in zip(grad_f, grad_l):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ------------------------------------------------------------ spec surface
