@@ -365,7 +365,9 @@ def execute(run: Run, check_chip: bool = True) -> dict:
               "failed": out.failed, "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
-    result["checks"] = {k: {"value": v, "limit": lim}
+    # plain floats: a numpy scalar's repr, np.float64(...), would hide the
+    # number from a reader of the check lines
+    result["checks"] = {k: {"value": float(v), "limit": lim}
                         for k, (v, lim) in out.checks.items()}
     return result
 

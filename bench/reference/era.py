@@ -74,20 +74,32 @@ def make_cell(scn) -> Cell:
                 jnp.asarray(suffix_mask(scn.dn_group_end)))
 
 
-def round_bits(x, bits):
-    """``x`` rounded to ``bits`` significant bits (8: bfloat16; 16: the
-    bfloat16 hi + lo pair), to nearest on the bit pattern, so that no
-    compiler can fold the rounding away as it may a pair of casts; None
-    leaves it as it is."""
-    if bits is None:
-        return x
-    if bits not in (8, 16):
-        raise ValueError(f"bits must be None, 8 or 16, got {bits!r}")
+@partial(jax.custom_jvp, nondiff_argnums=(1,))
+def _round_bits(x, bits):
     drop = 24 - bits                      # float32 keeps 24 significant bits
     u = jax.lax.bitcast_convert_type(x, jnp.uint32)
     u = (u + jnp.uint32(1 << (drop - 1))) & jnp.uint32(~((1 << drop) - 1)
                                                        & 0xFFFFFFFF)
     return jax.lax.bitcast_convert_type(u, jnp.float32)
+
+
+@_round_bits.defjvp
+def _round_bits_jvp(bits, primals, tangents):
+    # the derivative of rounding is taken as 1, as a lower-precision
+    # contraction's is: through the bit pattern it would be 0
+    return _round_bits(primals[0], bits), tangents[0]
+
+
+def round_bits(x, bits):
+    """``x`` rounded to ``bits`` significant bits (8: bfloat16; 16: the
+    bfloat16 hi + lo pair), to nearest on the bit pattern, so that no
+    compiler can fold the rounding away as it may a pair of casts; None
+    leaves it as it is.  Gradients pass through the rounding unchanged."""
+    if bits is None:
+        return x
+    if bits not in (8, 16):
+        raise ValueError(f"bits must be None, 8 or 16, got {bits!r}")
+    return _round_bits(x, bits)
 
 
 def _einsum(spec, *ops, bits=None):

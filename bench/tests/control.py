@@ -57,7 +57,7 @@ def main(argv=None):
                     help="run at the small CPU size of bench/tests/tiny.py")
     args = ap.parse_args(argv)
     import importlib
-    from bench.harness import core, solver
+    from bench.harness import core
     core.enable_compile_cache()
     for seed in [int(s) for s in args.seeds.split(",")]:
         cfg = None
@@ -78,9 +78,6 @@ def main(argv=None):
         driver = importlib.import_module(f"bench.harness.{name}")
         out = driver.run(run)
         program = {k: v for k, (v, _) in out.checks.items()}
-        program.update(solver.step_numbers(
-            out.readings["log"], run.config,
-            out.readings["log"].program_prof, out.readings["log"].weights))
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "program": program,
                           "control": control_numbers(run, out, "high"),

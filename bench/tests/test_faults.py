@@ -3,6 +3,7 @@ whole run at the small CPU size of ``tiny.py`` (the harness's look for a
 chip skipped) with one fault planted in the program underneath, and sees
 ``correct`` come out false.  The same runs unbroken come out true."""
 import contextlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +81,60 @@ CASES = [("paper.drift", "state_unchanged"),
 @pytest.mark.parametrize("cell", ["paper.drift", "internlm2.chat"])
 def test_sound_run_is_correct(cell):
     assert run(cell)["correct"]
+
+
+def test_every_check_carries_its_limit_and_the_step_its_place(capfd):
+    result = tiny.run_tiny("paper.drift", seed=11, seconds=0.5)
+    for k, c in result["checks"].items():
+        assert type(c["value"]) is float, (k, c)
+        assert isinstance(c["limit"], (int, float)), (k, c)
+    place, = [x for x in capfd.readouterr().err.splitlines()
+              if x.startswith("fused step: step_grad_p99 at ")]
+    for word in ("lane", "split", "leaf", "leaf norm", "median leaf norm"):
+        assert word in place, place
+    assert "np." not in place, place
+
+
+def _scaled_leaf(leaf):
+    """The fused step's gradient with one leaf scaled by 1 + 1e-5."""
+    def wrap(real):
+        def fn(*args, **kw):
+            val, g = real(*args, **kw)
+            return val, g._replace(**{leaf: getattr(g, leaf) * (1 + 1e-5)})
+        return fn
+    return wrap
+
+
+@pytest.mark.parametrize("leaf", ["beta_up", "p", "r"])
+def test_scaled_gradient_leaf_is_caught(leaf):
+    from repro.kernels.era_step import ops
+    with patched(ops, "era_step_value_and_grad", _scaled_leaf(leaf)):
+        result = run("paper.drift")
+    assert not result["correct"], result["checks"]
+    step = tiny.step_grad(result)
+    assert step["value"] > step["limit"]
+
+
+def _scaled_rows(real):
+    """The fused step's gradient with the rows of the last tenth of the
+    users (at least one) scaled by 1 + 1e-4: a fault in one block of
+    users, as a wrong last tile or an off-by-one padding mask makes."""
+    def fn(*args, **kw):
+        val, g = real(*args, **kw)
+        n = g.p.shape[0]
+        k = n - math.ceil(n / 10)
+        return val, jax.tree.map(lambda x: x.at[k:].multiply(1 + 1e-4), g)
+    return fn
+
+
+@pytest.mark.parametrize("cell", ["paper.drift", "internlm2.chat"])
+def test_scaled_gradient_rows_are_caught(cell):
+    from repro.kernels.era_step import ops
+    with patched(ops, "era_step_value_and_grad", _scaled_rows):
+        result = run(cell)
+    assert not result["correct"], result["checks"]
+    step = tiny.step_grad(result)
+    assert step["value"] > step["limit"]
 
 
 @pytest.mark.parametrize("cell,fault", CASES)

@@ -42,3 +42,11 @@ def run_tiny(cell: str, seed: int = 7, seconds: float = 2.0, trace=False,
         run.traffic = dict(run.traffic, **traffic)
     run.control = control
     return core.execute(run, check_chip=False)
+
+
+def step_grad(result: dict) -> dict:
+    """The check of the fused step's gradient (``step_grad_p<NN>``) in a
+    result object: its value and limit."""
+    (check,) = [c for k, c in result["checks"].items()
+                if k.startswith("step_grad_p")]
+    return check
