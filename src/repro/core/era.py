@@ -147,13 +147,14 @@ def clip_alloc(scn, alloc: Allocation) -> Allocation:
         b = jnp.clip(b, 0.0, 1.0)
         return b / jnp.maximum(b.sum(axis=1, keepdims=True), 1e-9)
 
-    return Allocation(
-        beta_up=simplex(alloc.beta_up),
-        beta_dn=simplex(alloc.beta_dn),
-        p=jnp.clip(alloc.p, env.p_min_w, env.p_max_w),
-        p_ap=jnp.clip(alloc.p_ap, env.ap_p_min_w, env.ap_p_max_w),
-        r=jnp.clip(alloc.r, env.r_min, env.r_max),
-    )
+    with jax.named_scope("era.clip_alloc"):
+        return Allocation(
+            beta_up=simplex(alloc.beta_up),
+            beta_dn=simplex(alloc.beta_dn),
+            p=jnp.clip(alloc.p, env.p_min_w, env.p_max_w),
+            p_ap=jnp.clip(alloc.p_ap, env.ap_p_min_w, env.ap_p_max_w),
+            r=jnp.clip(alloc.r, env.r_min, env.r_max),
+        )
 
 
 def round_beta(scn, alloc: Allocation, cap=None) -> Allocation:

@@ -108,6 +108,7 @@ import numpy as np
 from repro.core import network, noma, profiles
 from repro.core.era import (Allocation, Terms, Weights, clip_alloc,
                             round_beta, uniform_alloc, utility)
+from repro.telemetry import spans
 
 _BACKENDS = ("reference", "chunked", "sharded", "multihost")
 _BUCKETS = ("pow2", "exact", "full")
@@ -407,13 +408,15 @@ def _gd_core(scn, s_vec, q, x0, lr, tol, max_steps, w, prof,
     def body(carry):
         alloc, prev_val, k, _, cur_lr = carry
         val, g = grad_fn(alloc)
-        # guard against inf gradients from degenerate (near-zero-rate)
-        # allocations: 1/R² terms in eq. (34) blow up as R -> 0
-        g = jax.tree.map(lambda x: jnp.where(jnp.isfinite(x), x, 0.0), g)
-        gnorm = jnp.sqrt(sum(jnp.sum(x ** 2)
-                             for x in jax.tree_util.tree_leaves(g)))
-        step = jax.tree.map(
-            lambda gg, sc: cur_lr * sc * gg / (gnorm + 1e-12), g, scales)
+        with jax.named_scope("era.grad_norm"):
+            # guard against inf gradients from degenerate (near-zero-rate)
+            # allocations: 1/R² terms in eq. (34) blow up as R -> 0
+            g = jax.tree.map(lambda x: jnp.where(jnp.isfinite(x), x, 0.0),
+                             g)
+            gnorm = jnp.sqrt(sum(jnp.sum(x ** 2)
+                                 for x in jax.tree_util.tree_leaves(g)))
+            step = jax.tree.map(
+                lambda gg, sc: cur_lr * sc * gg / (gnorm + 1e-12), g, scales)
         new = clip_alloc(scn, Allocation(*[a - d for a, d in
                                            zip(alloc, step)]))
         if adaptive:
@@ -459,7 +462,9 @@ def _gd_core(scn, s_vec, q, x0, lr, tol, max_steps, w, prof,
         alloc, _, iters, _, _ = jax.lax.while_loop(cond, chunk_body, carry0)
     else:
         alloc, _, iters, _, _ = jax.lax.while_loop(cond, body, carry0)
-    return GDResult(alloc, loss(alloc), iters)
+    with jax.named_scope("era.loss"):
+        gamma = loss(alloc)
+    return GDResult(alloc, gamma, iters)
 
 
 # per-layer entry point (sequential reference path + ERA+ polish step):
@@ -962,157 +967,166 @@ def solve_batch(scns, prof, q, w: Weights = Weights(), *,
     interior (``soften_beta``) before seeding layer 0's GD, exactly as the
     single-cell ``solve(init_alloc=...)`` path does.
     """
-    spec = _resolve_spec(spec, "ligd.solve_batch", lr=lr, tol=tol,
-                         max_steps=max_steps, warm_start=warm_start,
-                         per_user_split=per_user_split, adaptive=adaptive,
-                         gd_chunk=gd_chunk, mesh=mesh,
-                         compiled_sweep=compiled_sweep)
-    if not spec.compiled_sweep:
-        raise ValueError(
-            "compiled_sweep=False is the per-layer sequential reference "
-            "loop, a single-cell path — use ligd.solve; solve_batch "
-            "always runs the scanned sweep")
-    if prep is None:
-        prep = prepare_batch(scns, prof, spec.warm_start)
-    scn_b, scn_list = prep.scn_b, prep.scn_list
-    prof_b, prof_list = prep.prof_b, prep.prof_list
-    prof_batched, pred_b = prep.prof_batched, prep.pred_b
-    n_cells = len(scn_list)
-    q = jnp.asarray(q)
-    if q.ndim != 2 or q.shape[0] != n_cells:
-        raise ValueError(f"q must be (B, U) with B={n_cells}, got {q.shape}")
+    # warm: spec, prep and the start point; sweep: from the dispatch to
+    # the Γ landscape on the host; finalize: the rest (telemetry.span)
+    with spans.span("era.solve.warm"):
+        spec = _resolve_spec(spec, "ligd.solve_batch", lr=lr, tol=tol,
+                             max_steps=max_steps, warm_start=warm_start,
+                             per_user_split=per_user_split, adaptive=adaptive,
+                             gd_chunk=gd_chunk, mesh=mesh,
+                             compiled_sweep=compiled_sweep)
+        if not spec.compiled_sweep:
+            raise ValueError(
+                "compiled_sweep=False is the per-layer sequential reference "
+                "loop, a single-cell path — use ligd.solve; solve_batch "
+                "always runs the scanned sweep")
+        if prep is None:
+            prep = prepare_batch(scns, prof, spec.warm_start)
+        scn_b, scn_list = prep.scn_b, prep.scn_list
+        prof_b, prof_list = prep.prof_b, prep.prof_list
+        prof_batched, pred_b = prep.prof_batched, prep.pred_b
+        n_cells = len(scn_list)
+        q = jnp.asarray(q)
+        if q.ndim != 2 or q.shape[0] != n_cells:
+            raise ValueError(f"q must be (B, U) with B={n_cells}, "
+                             f"got {q.shape}")
 
-    hetero = prep.hetero
-    if init_alloc is not None:
-        if not isinstance(init_alloc, Allocation) \
-                and isinstance(init_alloc, (list, tuple)):
-            init_alloc = stack_allocs(init_alloc)
-        if init_alloc.p.shape[0] != n_cells:
-            raise ValueError(f"init_alloc must carry a leading B={n_cells} "
-                             f"axis, got {init_alloc.p.shape}")
-        # soften_beta only needs n_subchannels (structural) — batched-safe
-        x_init = soften_beta(scn_list[0], init_alloc)
-        x_init_batched = True
-    elif hetero:
-        # per-cell box bounds => per-cell uninformed starts
-        x_init = stack_allocs([uniform_alloc(s) for s in scn_list])
-        x_init_batched = True
-    else:
-        x_init = uniform_alloc(scn_list[0])    # identical across cells
-        x_init_batched = False
-    f = prof_list[0].n_layers
-    u = q.shape[1]
-
-    run_mesh = spec.run_mesh()
-    if spec.backend == "multihost":
-        from repro.distributed import multihost
-        # host-local lanes in, host-local lanes out: the finalize tail
-        # below sees exactly this process's B lanes either way, so it is
-        # shared verbatim with the single-process backends.  No
-        # _LANE_ITERS recording — lane_placement='sorted' is rejected
-        # for multihost (cross-host history would defeat the point).
-        swept = multihost.multihost_sweep(
-            run_mesh, scn_b, q, x_init, jnp.asarray(pred_b),
-            spec.lr, spec.tol, spec.max_steps, w, prof_b,
-            adaptive=spec.adaptive, gd_chunk=spec.gd_chunk,
-            step_impl=spec.step_impl, step_block_m=spec.step_block_m,
-            prof_batched=prof_batched, x_init_batched=x_init_batched)
-    elif run_mesh is not None:
-        from repro.distributed import solver_mesh
-        lane_perm = None
-        if spec.lane_placement == "sorted":
-            lane_perm = _lane_permutation(n_cells, run_mesh.devices.size)
-        if lane_perm is not None:
-            perm_ix = jnp.asarray(lane_perm)
-            scn_sw = network.take_cells(scn_b, perm_ix)
-            q_sw = jnp.take(q, perm_ix, axis=0)
-            pred_sw = pred_b[lane_perm]
-            x_init_sw = (network.take_cells(x_init, perm_ix)
-                         if x_init_batched else x_init)
-            prof_sw = (network.take_cells(prof_b, perm_ix)
-                       if prof_batched else prof_b)
+        hetero = prep.hetero
+        if init_alloc is not None:
+            if not isinstance(init_alloc, Allocation) \
+                    and isinstance(init_alloc, (list, tuple)):
+                init_alloc = stack_allocs(init_alloc)
+            if init_alloc.p.shape[0] != n_cells:
+                raise ValueError(f"init_alloc must carry a leading "
+                                 f"B={n_cells} axis, got "
+                                 f"{init_alloc.p.shape}")
+            # soften_beta only needs n_subchannels (structural) —
+            # batched-safe
+            x_init = soften_beta(scn_list[0], init_alloc)
+            x_init_batched = True
+        elif hetero:
+            # per-cell box bounds => per-cell uninformed starts
+            x_init = stack_allocs([uniform_alloc(s) for s in scn_list])
+            x_init_batched = True
         else:
-            scn_sw, q_sw, pred_sw = scn_b, q, pred_b
-            x_init_sw, prof_sw = x_init, prof_b
-        swept = solver_mesh.sharded_sweep(
-            run_mesh, scn_sw, q_sw, x_init_sw, jnp.asarray(pred_sw),
-            spec.lr, spec.tol, spec.max_steps, w, prof_sw,
-            adaptive=spec.adaptive, gd_chunk=spec.gd_chunk,
-            step_impl=spec.step_impl, step_block_m=spec.step_block_m,
-            prof_batched=prof_batched, x_init_batched=x_init_batched)
-        if lane_perm is not None:
-            # per-lane GD is frozen-by-select under vmap, so a lane's
-            # result is independent of its co-resident lanes — inverting
-            # the permutation restores the 'none' ordering's outputs
-            # exactly (tests/test_sharded_solver.py asserts equality)
-            inv_ix = jnp.asarray(np.argsort(lane_perm))
-            swept = network.take_cells(swept, inv_ix)
-        # record this round's per-lane effort for the next same-size round
-        _LANE_ITERS[n_cells] = np.asarray(swept.iters).sum(axis=1)
-    else:
-        swept = _sweep_batch(scn_b, q, x_init, jnp.asarray(pred_b), spec.lr,
-                             spec.tol, spec.max_steps, w, prof_b,
-                             adaptive=spec.adaptive, gd_chunk=spec.gd_chunk,
-                             step_impl=spec.step_impl,
-                             step_block_m=spec.step_block_m,
-                             prof_batched=prof_batched,
-                             x_init_batched=x_init_batched)
+            x_init = uniform_alloc(scn_list[0])    # identical across cells
+            x_init_batched = False
+        f = prof_list[0].n_layers
+        u = q.shape[1]
+
+        run_mesh = spec.run_mesh()
+    with spans.span("era.solve.sweep"):
+        if spec.backend == "multihost":
+            from repro.distributed import multihost
+            # host-local lanes in, host-local lanes out: the finalize tail
+            # below sees exactly this process's B lanes either way, so it is
+            # shared verbatim with the single-process backends.  No
+            # _LANE_ITERS recording — lane_placement='sorted' is rejected
+            # for multihost (cross-host history would defeat the point).
+            swept = multihost.multihost_sweep(
+                run_mesh, scn_b, q, x_init, jnp.asarray(pred_b),
+                spec.lr, spec.tol, spec.max_steps, w, prof_b,
+                adaptive=spec.adaptive, gd_chunk=spec.gd_chunk,
+                step_impl=spec.step_impl, step_block_m=spec.step_block_m,
+                prof_batched=prof_batched, x_init_batched=x_init_batched)
+        elif run_mesh is not None:
+            from repro.distributed import solver_mesh
+            lane_perm = None
+            if spec.lane_placement == "sorted":
+                lane_perm = _lane_permutation(n_cells, run_mesh.devices.size)
+            if lane_perm is not None:
+                perm_ix = jnp.asarray(lane_perm)
+                scn_sw = network.take_cells(scn_b, perm_ix)
+                q_sw = jnp.take(q, perm_ix, axis=0)
+                pred_sw = pred_b[lane_perm]
+                x_init_sw = (network.take_cells(x_init, perm_ix)
+                             if x_init_batched else x_init)
+                prof_sw = (network.take_cells(prof_b, perm_ix)
+                           if prof_batched else prof_b)
+            else:
+                scn_sw, q_sw, pred_sw = scn_b, q, pred_b
+                x_init_sw, prof_sw = x_init, prof_b
+            swept = solver_mesh.sharded_sweep(
+                run_mesh, scn_sw, q_sw, x_init_sw, jnp.asarray(pred_sw),
+                spec.lr, spec.tol, spec.max_steps, w, prof_sw,
+                adaptive=spec.adaptive, gd_chunk=spec.gd_chunk,
+                step_impl=spec.step_impl, step_block_m=spec.step_block_m,
+                prof_batched=prof_batched, x_init_batched=x_init_batched)
+            if lane_perm is not None:
+                # per-lane GD is frozen-by-select under vmap, so a lane's
+                # result is independent of its co-resident lanes — inverting
+                # the permutation restores the 'none' ordering's outputs
+                # exactly (tests/test_sharded_solver.py asserts equality)
+                inv_ix = jnp.asarray(np.argsort(lane_perm))
+                swept = network.take_cells(swept, inv_ix)
+            # record this round's per-lane effort for the next same-size round
+            _LANE_ITERS[n_cells] = np.asarray(swept.iters).sum(axis=1)
+        else:
+            swept = _sweep_batch(scn_b, q, x_init, jnp.asarray(pred_b),
+                                 spec.lr, spec.tol, spec.max_steps, w, prof_b,
+                                 adaptive=spec.adaptive,
+                                 gd_chunk=spec.gd_chunk,
+                                 step_impl=spec.step_impl,
+                                 step_block_m=spec.step_block_m,
+                                 prof_batched=prof_batched,
+                                 x_init_batched=x_init_batched)
+        gammas = np.asarray(swept.gamma)                       # (B, F+1)
 
     # ---- batched finalize: every compiled stage is ONE dispatch for all
     # cells; only the greedy β rounding runs per cell (host-side) ----------
-    gammas = np.asarray(swept.gamma)                       # (B, F+1)
-    iters = np.asarray(swept.iters)
-    s_star = jnp.asarray(np.argmin(gammas, axis=1), jnp.int32)   # (B,)
-    # select layer s* per cell by a one-hot sum, not a gather: under a
-    # mesh with Explicit axes the gather's output sharding is ambiguous
-    # and raises, while select-and-sum keeps x's cells sharding (and is
-    # exact: every other term is 0.0)
-    pick = jax.nn.one_hot(s_star, gammas.shape[1], dtype=bool)   # (B, F+1)
+    with spans.span("era.solve.finalize"):
+        iters = np.asarray(swept.iters)
+        s_star = jnp.asarray(np.argmin(gammas, axis=1), jnp.int32)   # (B,)
+        # select layer s* per cell by a one-hot sum, not a gather: under a
+        # mesh with Explicit axes the gather's output sharding is ambiguous
+        # and raises, while select-and-sum keeps x's cells sharding (and is
+        # exact: every other term is 0.0)
+        pick = jax.nn.one_hot(s_star, gammas.shape[1], dtype=bool)   # (B, F+1)
 
-    def at_star(x):
-        mask = pick.reshape(pick.shape + (1,) * (x.ndim - 2))
-        return jnp.sum(jnp.where(mask, x, 0), axis=1)
+        def at_star(x):
+            mask = pick.reshape(pick.shape + (1,) * (x.ndim - 2))
+            return jnp.sum(jnp.where(mask, x, 0), axis=1)
 
-    if spec.per_user_split:
-        costs = _cost_table_batch(scn_b, q, swept.alloc, w, prof_b,
-                                  prof_batched=prof_batched)  # (B, F+1, U)
-        s_user = jnp.argmin(costs, axis=1).astype(jnp.int32)  # (B, U)
-        # polish per cell: polish iteration counts vary wildly across
-        # cells, so a vmapped (lockstep) polish would run every lane to the
-        # slowest cell's count — B small dispatches are cheaper here
-        x_star = jax.tree.map(at_star, swept.alloc)
-        polished = [
-            _gd_solve(scn_list[b], s_user[b], q[b],
-                      jax.tree.map(lambda x, b=b: x[b], x_star),
-                      spec.lr, spec.tol, spec.max_steps, w, prof_list[b],
-                      adaptive=spec.adaptive, step_impl=spec.step_impl,
-                      step_block_m=spec.step_block_m)
+        if spec.per_user_split:
+            costs = _cost_table_batch(scn_b, q, swept.alloc, w, prof_b,
+                                      prof_batched=prof_batched)  # (B, F+1, U)
+            s_user = jnp.argmin(costs, axis=1).astype(jnp.int32)  # (B, U)
+            # polish per cell: polish iteration counts vary wildly across
+            # cells, so a vmapped (lockstep) polish would run every lane to the
+            # slowest cell's count — B small dispatches are cheaper here
+            x_star = jax.tree.map(at_star, swept.alloc)
+            polished = [
+                _gd_solve(scn_list[b], s_user[b], q[b],
+                          jax.tree.map(lambda x, b=b: x[b], x_star),
+                          spec.lr, spec.tol, spec.max_steps, w, prof_list[b],
+                          adaptive=spec.adaptive, step_impl=spec.step_impl,
+                          step_block_m=spec.step_block_m)
+                for b in range(n_cells)
+            ]
+            alloc_b = jax.tree.map(lambda *xs: jnp.stack(xs),
+                                   *[p.alloc for p in polished])
+        else:
+            s_user = jnp.broadcast_to(s_star[:, None], (n_cells, u))
+            alloc_b = jax.tree.map(at_star, swept.alloc)
+
+        # discretise per cell (host greedy), then one batched SIC+Γ evaluation
+        hard_list = [round_beta(scn_list[b],
+                                jax.tree.map(lambda x, b=b: x[b], alloc_b))
+                     for b in range(n_cells)]
+        hard_b = jax.tree.map(lambda *xs: jnp.stack(xs), *hard_list)
+        s_final_b, terms_b = _discretize_eval_batch(
+            scn_b, s_user, hard_b, q, w, prof_b, f, prof_batched=prof_batched)
+
+        s_final_np = np.asarray(s_final_b)
+        terms_np = jax.tree.map(np.asarray, terms_b)
+        return [
+            LiGDOutcome(
+                s=s_final_np[b],
+                alloc=hard_list[b],
+                terms=Terms(*(leaf[b] for leaf in terms_np)),
+                gamma_by_layer=gammas[b],
+                iters_by_layer=iters[b],
+                total_iters=int(iters[b].sum()),
+            )
             for b in range(n_cells)
         ]
-        alloc_b = jax.tree.map(lambda *xs: jnp.stack(xs),
-                               *[p.alloc for p in polished])
-    else:
-        s_user = jnp.broadcast_to(s_star[:, None], (n_cells, u))
-        alloc_b = jax.tree.map(at_star, swept.alloc)
-
-    # discretise per cell (host greedy), then one batched SIC+Γ evaluation
-    hard_list = [round_beta(scn_list[b],
-                            jax.tree.map(lambda x, b=b: x[b], alloc_b))
-                 for b in range(n_cells)]
-    hard_b = jax.tree.map(lambda *xs: jnp.stack(xs), *hard_list)
-    s_final_b, terms_b = _discretize_eval_batch(
-        scn_b, s_user, hard_b, q, w, prof_b, f, prof_batched=prof_batched)
-
-    s_final_np = np.asarray(s_final_b)
-    terms_np = jax.tree.map(np.asarray, terms_b)
-    return [
-        LiGDOutcome(
-            s=s_final_np[b],
-            alloc=hard_list[b],
-            terms=Terms(*(leaf[b] for leaf in terms_np)),
-            gamma_by_layer=gammas[b],
-            iters_by_layer=iters[b],
-            total_iters=int(iters[b].sum()),
-        )
-        for b in range(n_cells)
-    ]

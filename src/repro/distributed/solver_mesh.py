@@ -92,7 +92,9 @@ def _sharded_sweep_fn(mesh, max_steps, w, adaptive, gd_chunk, step_impl,
     def local_sweep(scn_b, q_b, x_init, pred_b, lr, tol, prof):
         # one shard's lanes: the SAME vmapped sweep body _sweep_batch
         # jits, applied to the local slice — the sharded path can never
-        # diverge from the single-device reference
+        # diverge from the single-device reference, and carries the same
+        # named scopes (era.beta_t, era.grad_norm, era.clip_alloc,
+        # era.loss) on its ops
         return ligd._vmapped_sweep(
             scn_b, q_b, x_init, pred_b, lr, tol, max_steps, w, prof,
             adaptive=adaptive, gd_chunk=gd_chunk, step_impl=step_impl,

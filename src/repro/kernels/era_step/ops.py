@@ -121,9 +121,11 @@ def _operands(scn, prof, s_vec, q, alloc, aux, w):
         jnp.float32(0.0),
         jnp.float32(0.0),
     ])[None, :]                                       # (1, ref.ENV_LANES)
+    with jax.named_scope("era.beta_t"):
+        bu_t = alloc.beta_up.T.astype(jnp.float32)
+        bd_t = alloc.beta_dn.T.astype(jnp.float32)
     return (
-        alloc.beta_up.T.astype(jnp.float32),
-        alloc.beta_dn.T.astype(jnp.float32),
+        bu_t, bd_t,
         row(alloc.p), row(alloc.p_ap), row(alloc.r), row(q),
         row(prof.device_flops[s_vec]), row(prof.edge_flops[s_vec]),
         row(prof.uplink_bits[s_vec]), row(prof.downlink_bits[s_vec]),
@@ -168,6 +170,8 @@ def era_step_value_and_grad(scn, prof, s_vec, q, alloc, w, *, aux=None,
     else:
         raise ValueError(f"impl must be 'kernel' or 'ref', got {impl!r}")
     d_bu, d_bd, d_p, d_pap, d_r = grads
-    grad = Allocation(beta_up=d_bu.T, beta_dn=d_bd.T,
+    with jax.named_scope("era.beta_t"):
+        d_bu, d_bd = d_bu.T, d_bd.T
+    grad = Allocation(beta_up=d_bu, beta_dn=d_bd,
                       p=d_p[0], p_ap=d_pap[0], r=d_r[0])
     return jnp.reshape(gamma, ()), grad

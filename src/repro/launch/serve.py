@@ -16,7 +16,8 @@ background solver thread re-schedules on simulated arrivals and drift):
       --users 12 --cells 2 --async-admission --rounds 6 --arrival-rate 2
 
 Async mode always runs over a ``telemetry.TelemetryBus`` and ends with a
-summary table (rounds, p99 solve ms, QoE attainment).  ``--trace PATH``
+summary table (rounds, p99 solve ms, the sweep, finalize and decode
+phases' p50/p99 ms, compile requests, QoE attainment).  ``--trace PATH``
 lands every event as JSONL; ``--governor`` attaches the ``QoSGovernor``
 (defer low-drift cells under pressure, prioritise failing QoE).
 
@@ -316,6 +317,19 @@ def main():
             row("swap-to-serve p99 ms", f"{1e3*lag.p99:.1f}")
         if att and att.count:
             row("QoE attainment (mean)", f"{att.mean:.3f}")
+        # the rounds' phases (telemetry.span): where a round's time went,
+        # and how many XLA compile requests each kind of round made
+        for stream, fld in (("admission_round", "sweep_s"),
+                            ("admission_round", "finalize_s"),
+                            ("serve_round", "decode_s")):
+            s = bus.summary(stream, fld)
+            if s and s.count:
+                row(f"{fld[:-2]} p50/p99 ms",
+                    f"{1e3*s.p50:.1f} / {1e3*s.p99:.1f}")
+        for stream in ("admission_round", "serve_round"):
+            s = bus.summary(stream, "compiles")
+            if s and s.count:
+                row(f"{stream} compiles", int(round(s.mean * s.count)))
         row("serve rounds", bus.count("serve_round"))
         row("round errors", bus.count("round_error"))
         if governor is not None:

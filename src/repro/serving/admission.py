@@ -66,14 +66,20 @@ solver to burn power/compute on its lane).  Aging applies to what the
 SOLVE sees; the posted values (``current_q``) are preserved and a new
 arrival resets the user's age to zero.
 
-Telemetry (``bus=``, optional): every round phase lands on the
-``TelemetryBus`` — ``admission_round`` (arrival/touched/solved counts,
-solver wall time and iterations, per-phase durations), per-cell
+Telemetry (``bus=``, optional): each round lands on the ``TelemetryBus``
+as ``admission_round`` (arrival/touched/solved counts, iterations, the
+round's and the solve's wall time, and per-phase seconds and compile
+requests: ``ingest_s`` (drain through the governor), ``prep_s`` (the
+scenario scatter's dispatch), ``warm_s``/``sweep_s``/``finalize_s``
+(inside the solve), ``swap_s`` (install and publish), each with its
+``<phase>_compiles``, and the round's ``compiles``), per-cell
 ``qoe_attainment`` (fraction of users whose predicted delay beats their
 effective aged threshold — the paper's QoE target, finally measured),
 ``governor`` decisions and ``round_error`` for caught solver-round
-exceptions.  With no bus attached every emit site is a single
-``is not None`` check — the no-telemetry path allocates nothing.
+exceptions.  The phases are ``telemetry.span``s, so they also show on a
+profiler trace as ``era.round.*``/``era.solve.*``.  With no bus attached
+every emit site is a single ``is not None`` check and no recorder is
+made.
 
 QoS governor (``governor=``, optional): consulted between DRAIN and
 SOLVE.  Cells it defers are NOT solved this round; their queued work is
@@ -101,6 +107,7 @@ import numpy as np
 
 from repro.core import network
 from repro.serving.engine import MultiCellServeEngine
+from repro.telemetry import spans
 
 # bounded error backlog: always-on runs must never grow this without
 # bound (each caught round failure also lands as a `round_error` event)
@@ -445,61 +452,77 @@ class AdmissionController:
 
     def _step_locked(self) -> Optional[AdmissionRound]:
         t_wall0 = time.perf_counter()
-        arrivals, dirty = self.queue.drain()
-        # governor-deferred cells from previous rounds rejoin here: their
-        # arrivals' threshold updates were applied at their own drain, so
-        # a dirty mark is all the carried work they need
-        if self._deferred:
-            dirty |= self._deferred
-            self._deferred.clear()
-        if not arrivals and not dirty:
-            return None
-        t_start = self.clock()
         bus = self.bus
-        decision = None
-        with self._state_lock:
-            # bootstrap publishes _q under this lock; checking it out here
-            # (as this method once did) races a concurrent bootstrap into
-            # a half-initialised round instead of a clean error
-            if self._q is None:
-                raise RuntimeError(
-                    "bootstrap() before running admission rounds")
-            for a in arrivals:
-                self._q[a.cell, a.user] = a.q_s
-                self._t_posted[a.cell, a.user] = a.t
-            touched = sorted(dirty | {a.cell for a in arrivals})
-            drift = {b: network.scenario_drift(self._live[b], self._ref[b])
-                     for b in sorted(dirty)}
-            if self.governor is not None:
-                # the governor ranks by drift across the WHOLE touched
-                # set — arrival-only cells measure theirs here (skipped
-                # ungoverned: the round would not use it)
-                drift_all = dict(drift)
-                for b in touched:
-                    if b not in drift_all:
-                        drift_all[b] = network.scenario_drift(
-                            self._live[b], self._ref[b])
-                decision = self.governor.review(
-                    touched, drift_all, self._attainment, self.n_cells)
-            # snapshot the scenarios this round actually solves: _live may
-            # move again while the solve runs, and the drift reference must
-            # be the state the installed schedule was solved ON
-            solved = list(self._live)
-            q = self._effective_q_locked(t_start)
+        # the round's phases (ingest, prep, solve, swap) partition its wall
+        # time; with a bus attached a recorder sums each phase's seconds
+        # and compile requests, reported on the `admission_round` event
+        with spans.recording(bus is not None) as rec, \
+                spans.span("era.round"):
+            with spans.span("era.round.ingest"):
+                arrivals, dirty = self.queue.drain()
+                # governor-deferred cells from previous rounds rejoin
+                # here: their arrivals' threshold updates were applied at
+                # their own drain, so a dirty mark is all the carried work
+                # they need
+                if self._deferred:
+                    dirty |= self._deferred
+                    self._deferred.clear()
+                if not arrivals and not dirty:
+                    return None
+                t_start = self.clock()
+                decision = None
+                with self._state_lock:
+                    # bootstrap publishes _q under this lock; checking it
+                    # out here (as this method once did) races a
+                    # concurrent bootstrap into a half-initialised round
+                    # instead of a clean error
+                    if self._q is None:
+                        raise RuntimeError(
+                            "bootstrap() before running admission rounds")
+                    for a in arrivals:
+                        self._q[a.cell, a.user] = a.q_s
+                        self._t_posted[a.cell, a.user] = a.t
+                    touched = sorted(dirty | {a.cell for a in arrivals})
+                    drift = {b: network.scenario_drift(self._live[b],
+                                                       self._ref[b])
+                             for b in sorted(dirty)}
+                    if self.governor is not None:
+                        # the governor ranks by drift across the WHOLE
+                        # touched set — arrival-only cells measure theirs
+                        # here (skipped ungoverned: the round would not
+                        # use it)
+                        drift_all = dict(drift)
+                        for b in touched:
+                            if b not in drift_all:
+                                drift_all[b] = network.scenario_drift(
+                                    self._live[b], self._ref[b])
+                        decision = self.governor.review(
+                            touched, drift_all, self._attainment,
+                            self.n_cells)
+                    # snapshot the scenarios this round actually solves:
+                    # _live may move again while the solve runs, and the
+                    # drift reference must be the state the installed
+                    # schedule was solved ON
+                    solved = list(self._live)
+                    q = self._effective_q_locked(t_start)
 
-        if decision is not None:
-            self._deferred.update(decision.deferred)
-            if bus is not None:
-                for c in decision.deferred:
-                    bus.emit("governor", decision="deferred", cell=c,
-                             drift=float(drift_all.get(c, 0.0)),
-                             defer_count=self.governor.defer_count(c))
-                for c in decision.prioritised:
-                    bus.emit("governor", decision="prioritised", cell=c,
-                             attainment=float(self._attainment[c]))
-                for c in decision.forced:
-                    bus.emit("governor", decision="forced", cell=c)
-            if not decision.solve:
+                if decision is not None:
+                    self._deferred.update(decision.deferred)
+                    if bus is not None:
+                        for c in decision.deferred:
+                            bus.emit("governor", decision="deferred", cell=c,
+                                     drift=float(drift_all.get(c, 0.0)),
+                                     defer_count=self.governor.defer_count(c))
+                        for c in decision.prioritised:
+                            bus.emit("governor", decision="prioritised",
+                                     cell=c,
+                                     attainment=float(self._attainment[c]))
+                        for c in decision.forced:
+                            bus.emit("governor", decision="forced", cell=c)
+                    if decision.solve:
+                        touched = sorted(decision.solve)
+
+            if decision is not None and not decision.solve:
                 # fully shed round: nothing solves, nothing swaps; the
                 # deferred set re-arms the next round trigger
                 if bus is not None:
@@ -511,72 +534,86 @@ class AdmissionController:
                              n_touched=len(touched), n_solved=0,
                              n_deferred=len(decision.deferred),
                              n_prioritised=0, n_forced=0, iters=0,
+                             ingest_s=rec.seconds["era.round.ingest"],
                              round_wall_s=time.perf_counter() - t_wall0)
                 return None
-            touched = sorted(decision.solve)
 
-        # multi-process multihost schedulers route EVERY incremental
-        # round through the bucketed subset path (host-local solves):
-        # a full-mesh SPMD solve needs all processes in lockstep,
-        # which this host's arrival/drift queue cannot arrange
-        partial = self.partial_batch and (
-            len(touched) < self.n_cells
-            or getattr(self.scheduler, "host_local_rounds", False))
+            # multi-process multihost schedulers route EVERY incremental
+            # round through the bucketed subset path (host-local solves):
+            # a full-mesh SPMD solve needs all processes in lockstep,
+            # which this host's arrival/drift queue cannot arrange
+            partial = self.partial_batch and (
+                len(touched) < self.n_cells
+                or getattr(self.scheduler, "host_local_rounds", False))
 
-        # outside the lock: scheduler state belongs to this (single-
-        # consumer) round, and the scatter/restack dispatches must not
-        # stall serving-side submit()/observe_scenario() producers.
-        # Partial rounds scatter only the touched lanes into the stacked
-        # prep (O(k) host work); full rounds restack all B.
-        self.scheduler.update_scenarios(
-            solved, cells=touched if partial else None)
+            # outside the lock: scheduler state belongs to this (single-
+            # consumer) round, and the scatter/restack dispatches must not
+            # stall serving-side submit()/observe_scenario() producers.
+            # Partial rounds scatter only the touched lanes into the
+            # stacked prep (O(k) host work); full rounds restack all B.
+            with spans.span("era.round.prep"):
+                self.scheduler.update_scenarios(
+                    solved, cells=touched if partial else None)
 
-        t_solve0 = time.perf_counter()
-        if partial:
-            subset = self.scheduler.schedule(q, warm=self.warm_start,
-                                             cells=touched)
-            per_cell = dict(zip(touched, subset))
-            iters = sum(s.iters for s in subset)      # this round's lanes
-        else:
-            scheds = self.scheduler.schedule(q, warm=self.warm_start)
-            per_cell = {b: scheds[b] for b in touched}
-            iters = sum(s.iters for s in scheds)      # all B lanes solved
-        solve_s = time.perf_counter() - t_solve0
-        version = self.engine.swap_schedules(per_cell)
+            with spans.span("era.round.solve"):
+                t_solve0 = time.perf_counter()
+                if partial:
+                    subset = self.scheduler.schedule(
+                        q, warm=self.warm_start, cells=touched)
+                    per_cell = dict(zip(touched, subset))
+                    iters = sum(s.iters for s in subset)  # this round's lanes
+                else:
+                    scheds = self.scheduler.schedule(q, warm=self.warm_start)
+                    per_cell = {b: scheds[b] for b in touched}
+                    iters = sum(s.iters for s in scheds)  # all B lanes solved
+                solve_s = time.perf_counter() - t_solve0
 
-        rnd = AdmissionRound(
-            version=version, cells=tuple(touched),
-            n_arrivals=len(arrivals), drift=drift, total_iters=iters,
-            t_start=t_start, t_installed=self.clock())
-        with self._state_lock:
-            for b in touched:
-                self._ref[b] = solved[b]
-                self._attainment[b] = qoe_attainment(per_cell[b], q[b])
-            # _last_round_t is read lock-free-ish by the solver thread's
-            # batching window (_batching_wait_s snapshots it under this
-            # lock) — publish it under the same lock as every other writer
-            self._last_round_t = rnd.t_installed
-        self.rounds.append(rnd)
-        if bus is not None:
-            bus.emit("admission_round", version=version,
-                     n_arrivals=len(arrivals),
-                     n_touched=len(touched) if decision is None
-                     else len(touched) + len(decision.deferred),
-                     n_solved=len(touched),
-                     n_deferred=0 if decision is None
-                     else len(decision.deferred),
-                     n_prioritised=0 if decision is None
-                     else len(decision.prioritised),
-                     n_forced=0 if decision is None
-                     else len(decision.forced),
-                     iters=iters, solve_wall_s=solve_s,
-                     round_wall_s=time.perf_counter() - t_wall0)
-            for b in touched:
-                bus.emit("qoe_attainment", cell=b,
-                         attainment=float(self._attainment[b]),
-                         version=version)
-        self.round_done.set()
-        return rnd
+            with spans.span("era.round.swap"):
+                version = self.engine.swap_schedules(per_cell)
+                rnd = AdmissionRound(
+                    version=version, cells=tuple(touched),
+                    n_arrivals=len(arrivals), drift=drift,
+                    total_iters=iters, t_start=t_start,
+                    t_installed=self.clock())
+                with self._state_lock:
+                    for b in touched:
+                        self._ref[b] = solved[b]
+                        self._attainment[b] = qoe_attainment(per_cell[b],
+                                                             q[b])
+                    # _last_round_t is read lock-free-ish by the solver
+                    # thread's batching window (_batching_wait_s snapshots
+                    # it under this lock) — publish it under the same lock
+                    # as every other writer
+                    self._last_round_t = rnd.t_installed
+                self.rounds.append(rnd)
+
+            if bus is not None:
+                bus.emit("admission_round", version=version,
+                         n_arrivals=len(arrivals),
+                         n_touched=len(touched) if decision is None
+                         else len(touched) + len(decision.deferred),
+                         n_solved=len(touched),
+                         n_deferred=0 if decision is None
+                         else len(decision.deferred),
+                         n_prioritised=0 if decision is None
+                         else len(decision.prioritised),
+                         n_forced=0 if decision is None
+                         else len(decision.forced),
+                         iters=iters, solve_wall_s=solve_s,
+                         **rec.fields(ingest="era.round.ingest",
+                                      prep="era.round.prep",
+                                      warm="era.solve.warm",
+                                      sweep="era.solve.sweep",
+                                      finalize="era.solve.finalize",
+                                      swap="era.round.swap"),
+                         compiles=rec.total_compiles,
+                         round_wall_s=time.perf_counter() - t_wall0)
+                for b in touched:
+                    bus.emit("qoe_attainment", cell=b,
+                             attainment=float(self._attainment[b]),
+                             version=version)
+            self.round_done.set()
+            return rnd
 
     # ---- cell churn (coordinated join/leave) --------------------------
     @contextmanager

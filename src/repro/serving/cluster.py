@@ -68,6 +68,7 @@ from repro.core.ligd import SolverSpec
 from repro.serving.admission import AdmissionController, AdmissionRound
 from repro.serving.engine import MultiCellServeEngine, RequestResult
 from repro.serving.scheduler import MultiCellScheduler, Schedule
+from repro.telemetry import spans
 
 # Stable handle for one cell, valid across join/leave for the cluster
 # lifetime.  NEVER a lane index: lanes shift on churn, CellIds do not.
@@ -390,29 +391,45 @@ class SplitInferenceCluster:
         holds the same lock while it remaps them, so a concurrent
         add/remove can never pair this round's ids with a
         differently-shaped schedule/profile set (the round then executes
-        outside the lock, on its own snapshot)."""
+        outside the lock, on its own snapshot).
+
+        With a bus, the ``serve_round`` event carries the round's phases
+        (``telemetry.span``): ``serve_s``, ``first_token_s`` (one offset
+        from the round's start per split group, cells in lane order),
+        ``prefill_s``, ``decode_s`` and ``decode_steps`` (summed over
+        cells), and the compile requests of prefill, decode and the whole
+        round."""
         self._require_started()
-        with self._lock:
-            ids = list(self._ids)
-            ss, scns, profs = self.engine.round_snapshot()
-        if ss is None:
-            raise RuntimeError("no schedules installed yet")
-        if isinstance(tokens_by_cell, dict):
-            missing = [c for c in ids if c not in tokens_by_cell]
-            if missing:
-                raise ValueError(f"missing tokens for cells {missing}")
-            tokens = [tokens_by_cell[c] for c in ids]
-        else:
-            tokens = tokens_by_cell
-            if len(tokens) != len(ids):
-                raise ValueError(f"need tokens for {len(ids)} cells, "
-                                 f"got {len(tokens)}")
-        rounds = self.engine.serve_snapshot(ss, scns, profs, tokens,
-                                            decode_steps=decode_steps)
-        if self.bus is not None:
+        with spans.recording(self.bus is not None) as rec, \
+                spans.span("era.serve"):
+            with self._lock:
+                ids = list(self._ids)
+                ss, scns, profs = self.engine.round_snapshot()
+            if ss is None:
+                raise RuntimeError("no schedules installed yet")
+            if isinstance(tokens_by_cell, dict):
+                missing = [c for c in ids if c not in tokens_by_cell]
+                if missing:
+                    raise ValueError(f"missing tokens for cells {missing}")
+                tokens = [tokens_by_cell[c] for c in ids]
+            else:
+                tokens = tokens_by_cell
+                if len(tokens) != len(ids):
+                    raise ValueError(f"need tokens for {len(ids)} cells, "
+                                     f"got {len(tokens)}")
+            rounds = self.engine.serve_snapshot(ss, scns, profs, tokens,
+                                                decode_steps=decode_steps)
+        if rec is not None:
             self.bus.emit("serve_round", version=ss.version,
                           n_cells=len(ids),
-                          n_users=sum(len(r) for r in rounds))
+                          n_users=sum(len(r) for r in rounds),
+                          serve_s=rec.seconds["era.serve"],
+                          first_token_s=rec.marks.get("first_token", []),
+                          **rec.fields(prefill="era.serve.prefill",
+                                       decode="era.serve.decode_step"),
+                          decode_steps=rec.counts.get(
+                              "era.serve.decode_step", 0),
+                          compiles=rec.total_compiles)
         return {cid: res for cid, res in zip(ids, rounds)}
 
     # ---- per-cell state, keyed by CellId (tests / observability) -------

@@ -32,6 +32,7 @@ from repro.models import transformer as T
 from repro.serving import split_runtime
 from repro.serving.scheduler import (EraScheduler, MultiCellScheduler,
                                      Schedule)
+from repro.telemetry import spans
 
 
 @dataclass
@@ -52,12 +53,17 @@ def execute_schedule(params, cfg, netcfg, prof, sched: Schedule,
     results: Dict[int, RequestResult] = {}
 
     for split, users in sched.groups().items():
-        toks = tokens_per_user[users]
-        x, positions = split_runtime.device_forward(params, cfg, toks, split)
+        # the group span ends where its first tokens reach the host
+        with spans.span("era.serve.group", split=int(split),
+                        users=len(users)):
+            toks = tokens_per_user[users]
+            x, positions = split_runtime.device_forward(params, cfg, toks,
+                                                        split)
+            logits = split_runtime.edge_forward(params, cfg, x, positions,
+                                                split)
+            next_tok = np.asarray(jnp.argmax(logits[:, -1], -1))
+            spans.mark("first_token")
         crossing_bits = (float(x[0].size) * x.dtype.itemsize * 8)
-
-        logits = split_runtime.edge_forward(params, cfg, x, positions, split)
-        next_tok = np.asarray(jnp.argmax(logits[:, -1], -1))
 
         dev_fl = float(prof.device_flops[split])
         edge_fl = float(prof.edge_flops[split])
@@ -90,15 +96,18 @@ def _continue_decode(params, cfg, tokens, results, n_steps):
     # sequence length is the LAST axis — multi-codebook models carry
     # (U, n_codebooks, S) tokens, where shape[1] would be n_codebooks
     s = tokens.shape[-1]
-    logits, caches, _ = T.prefill(params, cfg, tokens,
-                                  max_seq=s + n_steps + 1)
-    cur = jnp.argmax(logits[:, -1], -1)
-    outs = [np.asarray(cur)]
+    # each span ends at the host read of its token that the loop needs
+    with spans.span("era.serve.prefill"):
+        logits, caches, _ = T.prefill(params, cfg, tokens,
+                                      max_seq=s + n_steps + 1)
+        cur = jnp.argmax(logits[:, -1], -1)
+        outs = [np.asarray(cur)]
     for step in range(n_steps - 1):
-        logits, caches = T.decode_step(params, cfg, cur,
-                                       jnp.int32(s + step), caches)
-        cur = jnp.argmax(logits, -1)
-        outs.append(np.asarray(cur))
+        with spans.span("era.serve.decode_step"):
+            logits, caches = T.decode_step(params, cfg, cur,
+                                           jnp.int32(s + step), caches)
+            cur = jnp.argmax(logits, -1)
+            outs.append(np.asarray(cur))
     seq = np.stack(outs, 1)
     for u, r in results.items():
         r.tokens_out = seq[u]
@@ -184,9 +193,6 @@ class MultiCellServeEngine:
             version = (self._installed.version + 1) if self._installed else 1
             self._installed = ScheduleSet(version, scheds)
             self._pending_serve[version] = self.clock()
-        if self.bus is not None:
-            self.bus.emit("schedule_swap", version=version,
-                          n_swapped=len(scheds), kind="install")
         return version
 
     def swap_schedules(self, per_cell: Dict[int, Schedule]) -> int:
@@ -205,9 +211,6 @@ class MultiCellServeEngine:
             version = self._installed.version + 1
             self._installed = ScheduleSet(version, tuple(scheds))
             self._pending_serve[version] = self.clock()
-        if self.bus is not None:
-            self.bus.emit("schedule_swap", version=version,
-                          n_swapped=len(per_cell), kind="swap")
         return version
 
     def resize(self, scns, schedules=None, keep: Dict[int, int] = None
@@ -270,9 +273,6 @@ class MultiCellServeEngine:
             self.scns = scns
             self._installed = ScheduleSet(version, tuple(scheds))
             self._pending_serve[version] = self.clock()
-        if self.bus is not None:
-            self.bus.emit("schedule_swap", version=version,
-                          n_swapped=len(scheds), kind="resize")
         return version
 
     def current_schedules(self) -> Optional[ScheduleSet]:

@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.core import era, ligd, network, noma, profiles
 from repro.core.era import Weights
+from repro.telemetry import spans
 
 
 @jax.jit
@@ -473,28 +474,45 @@ class MultiCellScheduler:
         exactly its one lane regardless of policy).  Returns Schedules
         aligned with ``cells`` order; other cells' warm-start state is
         left untouched."""
-        q = jnp.asarray(q_per_cell)
-        if cells is not None:
-            return self._schedule_subset(q, list(cells), warm=warm,
-                                         init_alloc=init_alloc,
-                                         bucket=bucket,
-                                         warm_overrides=warm_overrides)
-        if init_alloc is None and warm:
-            init_alloc = self._warm_init(range(self.n_cells),
-                                         overrides=warm_overrides)
-        outs = ligd.solve_batch(self.scns, self.prof, q, self.weights,
-                                spec=self.spec, prep=self.prep,
-                                init_alloc=init_alloc)
-        self.last_outcomes = list(outs)
-        return [build_schedule(scn, out)
-                for scn, out in zip(self.scns, outs)]
+        with spans.span("era.solve.warm"):
+            q = jnp.asarray(q_per_cell)
+            if cells is None:
+                lanes = list(range(self.n_cells))
+                prep, spec = self.prep, self.spec
+            else:
+                cells = list(cells)
+                if not cells:
+                    return []
+                lanes = self._subset_lanes(q, cells, bucket)
+                # identity lanes (k == B in order, or the 'full' policy
+                # landing on an in-order full set) reuse the stored prep —
+                # no gather needed
+                prep = self.prep if lanes == list(range(self.n_cells)) \
+                    else self._prep_subset(lanes)
+                q = q[jnp.asarray(lanes)]
+                # subset rounds run host-local under a multi-process
+                # multihost spec (same GD statics => bitwise-identical
+                # per-lane results)
+                spec = self._host_spec or self.spec
+            if init_alloc is None and warm:
+                init_alloc = self._warm_init(lanes, overrides=warm_overrides)
+        outs = ligd.solve_batch(None, None, q, self.weights, spec=spec,
+                                prep=prep, init_alloc=init_alloc)
+        with spans.span("era.solve.finalize"):
+            if cells is None:
+                cells = lanes
+                self.last_outcomes = list(outs)
+            else:
+                if not self.last_outcomes:
+                    self.last_outcomes = [None] * self.n_cells
+                for j, c in enumerate(cells):          # real lanes only
+                    self.last_outcomes[c] = outs[j]
+            return [build_schedule(self.scns[c], outs[j])
+                    for j, c in enumerate(cells)]
 
-    def _schedule_subset(self, q, cells: List[int], *, warm: bool,
-                         init_alloc=None, bucket: str = None,
-                         warm_overrides: Dict[int, Dict] = None
-                         ) -> List[Schedule]:
-        if not cells:
-            return []
+    def _subset_lanes(self, q, cells: List[int], bucket: str = None
+                      ) -> List[int]:
+        """The padded lane list for a partial round over ``cells``."""
         if sorted(set(cells)) != sorted(cells) or \
                 not all(0 <= c < self.n_cells for c in cells):
             raise ValueError(f"cells must be distinct indices in "
@@ -507,22 +525,4 @@ class MultiCellScheduler:
                              f"threshold matrix, got {q.shape}")
         k = len(cells)
         n = bucket_for(k, self.n_cells, bucket or self.spec.bucket)
-        lanes = cells + [cells[-1]] * (n - k)      # pad: repeat last cell
-        # identity lanes (k == B in order, or the 'full' policy landing on
-        # an in-order full set) reuse the stored prep — no gather needed
-        prep = self.prep if lanes == list(range(self.n_cells)) \
-            else self._prep_subset(lanes)
-        q_sub = q[jnp.asarray(lanes)]
-        if init_alloc is None and warm:
-            init_alloc = self._warm_init(lanes, overrides=warm_overrides)
-        # subset rounds run host-local under a multi-process multihost
-        # spec (same GD statics => bitwise-identical per-lane results)
-        outs = ligd.solve_batch(None, None, q_sub, self.weights,
-                                spec=self._host_spec or self.spec,
-                                prep=prep, init_alloc=init_alloc)
-        if not self.last_outcomes:
-            self.last_outcomes = [None] * self.n_cells
-        for j, c in enumerate(cells):              # real lanes only
-            self.last_outcomes[c] = outs[j]
-        return [build_schedule(self.scns[c], outs[j])
-                for j, c in enumerate(cells)]
+        return cells + [cells[-1]] * (n - k)      # pad: repeat last cell

@@ -7,9 +7,15 @@ emitters.  Integration layer: the admission/engine/cluster event streams
 documented in README "Observability" actually appear — round phases,
 solve wall time, swap-to-serve lag, per-cell QoE attainment, bounded
 ``round_error`` backlog — all under a fake clock, no numpy sort on the
-emit path."""
+emit path.  Phase spans (``telemetry.span``): the admission round's and
+the serve round's phase fields partition their wall time, compile
+requests land in the phase that made them, the ``era.`` spans show on a
+profiler trace with their bus fields' durations, and the sweep's ops
+carry their named scopes."""
+import glob
 import io
 import json
+import os
 import threading
 import tracemalloc
 
@@ -20,6 +26,8 @@ import repro.telemetry.bus as bus_mod
 from repro.telemetry import Event, FileSink, TelemetryBus
 
 pytestmark = pytest.mark.telemetry
+
+TELEMETRY_DIR = os.path.dirname(bus_mod.__file__)
 
 
 class FakeClock:
@@ -221,7 +229,9 @@ def test_serving_stack_emits_documented_streams():
     n_lags = len(lags)
     cluster.engine.round_snapshot()           # same version: no new lag
     assert len(bus.snapshot("swap_to_serve")) == n_lags
-    assert bus.count("schedule_swap") == 2    # install + swap
+    # installs are reported by the operation that made them (bootstrap,
+    # admission_round, churn, handover), not by a stream of their own
+    assert "schedule_swap" not in bus.streams()
     cluster.stop(drain=False)
 
 
@@ -275,7 +285,8 @@ def test_round_error_event_and_bounded_backlog():
 
 def test_no_bus_path_touches_no_telemetry():
     # the bus=None serving path must stay allocation-free w.r.t. the
-    # telemetry package: no Event, no ring, no sketch updates
+    # telemetry package: no Event, no ring, no sketch updates, no span
+    # recorder
     cluster, ids, clock = _cluster(None)
     tracemalloc.start()
     try:
@@ -284,8 +295,207 @@ def test_no_bus_path_touches_no_telemetry():
         cluster.step()
         cluster.engine.round_snapshot()
         snap = tracemalloc.take_snapshot().filter_traces(
-            [tracemalloc.Filter(True, bus_mod.__file__)])
+            [tracemalloc.Filter(True, os.path.join(TELEMETRY_DIR, "*"))])
         assert sum(s.size for s in snap.statistics("filename")) == 0
     finally:
         tracemalloc.stop()
         cluster.stop(drain=False)
+
+
+# ------------------------------------------- phase spans (telemetry.span)
+ROUND_PHASES = ("ingest", "prep", "warm", "sweep", "finalize", "swap")
+
+
+def _admission_round(bus, cluster, ids, clock, cells):
+    clock.advance(1.0)
+    for c in cells:
+        cluster.submit(ids[c], 1, 0.2)
+    assert cluster.step() is not None
+    return bus.snapshot("admission_round")[-1].fields
+
+
+@pytest.mark.parametrize("cells", [(0,), (0, 1)], ids=["partial", "full"])
+def test_admission_round_phases_partition_round_and_solve(cells):
+    bus = TelemetryBus()
+    cluster, ids, clock = _cluster(bus)
+    for _ in range(2):                        # a compiling round, a steady
+        ev = _admission_round(bus, cluster, ids, clock, cells)
+        for p in ROUND_PHASES:
+            assert ev[f"{p}_s"] > 0, p
+            assert type(ev[f"{p}_compiles"]) is int, p
+        assert type(ev["compiles"]) is int
+        outer = ev["ingest_s"] + ev["prep_s"] + ev["solve_wall_s"] \
+            + ev["swap_s"]
+        assert 0.95 * ev["round_wall_s"] <= outer <= ev["round_wall_s"]
+        inner = ev["warm_s"] + ev["sweep_s"] + ev["finalize_s"]
+        assert 0.95 * ev["solve_wall_s"] <= inner <= ev["solve_wall_s"]
+    cluster.stop(drain=False)
+
+
+def test_new_shape_compile_lands_in_its_phase():
+    from repro.core import ligd
+
+    bus = TelemetryBus()
+    cluster, ids, clock = _cluster(bus)
+    # the round's sweep compiles anew; nothing else in the round does
+    ligd._sweep_batch.clear_cache()
+    ev = _admission_round(bus, cluster, ids, clock, (0, 1))
+    assert ev["sweep_compiles"] >= 1
+    assert ev["compiles"] == ev["sweep_compiles"]
+    assert all(ev[f"{p}_compiles"] == 0 for p in ROUND_PHASES
+               if p != "sweep")
+    ev = _admission_round(bus, cluster, ids, clock, (0, 1))
+    assert ev["compiles"] == 0                # the same shapes again
+    cluster.stop(drain=False)
+
+
+def _serve_cluster(bus, seq=12, n_cells=2, n_users=4):
+    import jax
+
+    from repro.configs import get_tiny_config
+    from repro.core import network, profiles
+    from repro.core.ligd import SolverSpec
+    from repro.models import transformer as T
+    from repro.serving.cluster import SplitInferenceCluster
+
+    cfg = get_tiny_config("gemma-2b").replace(dtype="float32")
+    params = T.init(jax.random.PRNGKey(0), cfg)
+    ncfg = network.small_config(n_users=n_users, n_subchannels=3)
+    clock = FakeClock()
+    bus.clock = clock
+    cluster = SplitInferenceCluster(
+        params, cfg, profiles.transformer_profile(cfg, seq=seq),
+        spec=SolverSpec(max_steps=5, per_user_split=False), clock=clock,
+        bus=bus)
+    ids = [cluster.add_cell(network.make_scenario(jax.random.PRNGKey(b),
+                                                  ncfg), 0.4)
+           for b in range(n_cells)]
+    cluster.start(threaded=False)
+    tokens = {c: np.asarray(jax.random.randint(
+        jax.random.PRNGKey(10 + i), (n_users, seq), 0, cfg.vocab_size))
+        for i, c in enumerate(ids)}
+    return cluster, ids, clock, tokens
+
+
+@pytest.mark.parametrize("steps", [1, 3])
+def test_serve_round_carries_first_tokens_and_decode_phases(steps):
+    bus = TelemetryBus()
+    # a prompt length no other test serves: the round's prefill compiles
+    cluster, ids, clock, tokens = _serve_cluster(bus, seq=8 + steps)
+    out = cluster.serve_round(tokens, decode_steps=steps)
+    ev = bus.snapshot("serve_round")[-1].fields
+    n_groups = sum(len(cluster.installed_schedule(c).groups())
+                   for c in ids)
+    assert len(ev["first_token_s"]) == n_groups
+    assert ev["first_token_s"] == sorted(ev["first_token_s"])
+    assert 0 < ev["first_token_s"][0] and \
+        ev["first_token_s"][-1] < ev["serve_s"]
+    # the prefill gives each request its first token, a decode step each
+    # further one
+    assert ev["decode_steps"] == len(ids) * (steps - 1)
+    assert all(r.tokens_out.shape == (steps,) for rs in out.values()
+               for r in rs)
+    assert ev["prefill_s"] > 0
+    assert (ev["decode_s"] > 0) == (steps > 1)
+    assert ev["prefill_s"] + ev["decode_s"] < ev["serve_s"]
+    assert ev["prefill_compiles"] >= 1
+    assert ev["compiles"] >= ev["prefill_compiles"] + ev["decode_compiles"]
+    cluster.stop(drain=False)
+
+
+# spans and the bus fields that report them: (span name, event, field)
+SPAN_FIELDS = [
+    ("era.round.ingest", "admission_round", "ingest_s"),
+    ("era.round.prep", "admission_round", "prep_s"),
+    ("era.round.solve", "admission_round", "solve_wall_s"),
+    ("era.round.swap", "admission_round", "swap_s"),
+    ("era.solve.warm", "admission_round", "warm_s"),
+    ("era.solve.sweep", "admission_round", "sweep_s"),
+    ("era.solve.finalize", "admission_round", "finalize_s"),
+    ("era.serve", "serve_round", "serve_s"),
+    ("era.serve.prefill", "serve_round", "prefill_s"),
+    ("era.serve.decode_step", "serve_round", "decode_s"),
+]
+NESTING = {"era.round.": "era.round", "era.solve.": "era.round.solve",
+           "era.serve.": "era.serve"}
+
+
+def test_phase_spans_on_the_profiler_clock(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    bus = TelemetryBus()
+    cluster, ids, clock, tokens = _serve_cluster(bus)
+    _admission_round(bus, cluster, ids, clock, (0,))    # compile outside
+    cluster.serve_round(tokens, decode_steps=3)
+    with jax.profiler.trace(str(tmp_path)):
+        _admission_round(bus, cluster, ids, clock, (0,))
+        cluster.serve_round(tokens, decode_steps=3)
+    cluster.stop(drain=False)
+    fields = {name: bus.snapshot(name)[-1].fields
+              for name in ("admission_round", "serve_round")}
+
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = [(e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:CPU")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("era.")]
+    by_name = {}
+    for name, s, e in spans:
+        by_name.setdefault(name, []).append((s, e))
+    assert len(by_name["era.round"]) == len(by_name["era.serve"]) == 1
+    assert len(by_name["era.serve.group"]) == \
+        len(fields["serve_round"]["first_token_s"])
+    assert len(by_name["era.serve.decode_step"]) == \
+        fields["serve_round"]["decode_steps"]
+    for name, s, e in spans:
+        parent = next((p for pre, p in NESTING.items()
+                       if name.startswith(pre)), None)
+        if parent is None:
+            continue
+        (ps, pe), = by_name[parent]
+        assert ps <= s and e <= pe, (name, parent)
+    for name, event, field in SPAN_FIELDS:
+        traced = 1e-9 * sum(e - s for s, e in by_name[name])
+        bus_s = fields[event][field]
+        assert abs(traced - bus_s) <= 1e-3 + 0.05 * bus_s, \
+            (name, traced, bus_s)
+
+
+SWEEP_SCOPES = {"era.beta_t", "era.grad_norm", "era.clip_alloc", "era.loss"}
+
+
+@pytest.mark.parametrize("backend", ["reference", "sharded"])
+def test_sweep_ops_carry_named_scopes(backend):
+    """The GD step's XLA-side parts carry their named scopes in the
+    compiled sweep's op metadata, on one device and under the mesh."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import ligd, network, profiles
+    from repro.core.era import Weights, uniform_alloc
+    from repro.distributed import solver_mesh
+
+    ncfg = network.small_config(n_users=6, n_subchannels=3)
+    scns = [network.make_scenario(jax.random.PRNGKey(i), ncfg)
+            for i in range(2)]
+    prep = ligd.prepare_batch(scns, profiles.get_profile("nin"), True)
+    args = (prep.scn_b, jnp.full((2, 6), 0.4), uniform_alloc(scns[0]),
+            jnp.asarray(prep.pred_b), jnp.float32(0.05), jnp.float32(1e-5))
+    if backend == "reference":
+        lowered = ligd._sweep_batch.lower(
+            *args, 5, Weights(), prep.prof_b, step_impl="fused",
+            prof_batched=prep.prof_batched, x_init_batched=False)
+    else:
+        fn = solver_mesh._sharded_sweep_fn(
+            solver_mesh.cells_mesh(1), 5, Weights(), False, 0, "fused", 0,
+            prep.prof_batched, False)
+        lowered = fn.lower(*args, prep.prof_b)
+    text = lowered.compile().as_text()
+    scopes = {s for op in re.findall(r'op_name="([^"]*)"', text)
+              for s in re.findall(r"era\.[a-z_]+", op)}
+    assert SWEEP_SCOPES <= scopes, scopes
