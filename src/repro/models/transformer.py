@@ -158,11 +158,13 @@ def init_caches(cfg, batch, max_seq, dtype=None):
     return caches
 
 
+@partial(jax.jit, static_argnames=("cfg", "max_seq", "impl", "constrain"))
 def prefill(params, cfg, tokens, max_seq, vision_embeds=None, positions=None,
             impl="naive", constrain=_noop):
     """Full-sequence forward + decode-cache capture.
 
-    Returns (logits, caches, aux).
+    Compiled once per (cfg, shapes, max_seq, impl, constrain) and cached
+    across calls.  Returns (logits, caches, aux).
     """
     x = embed_tokens(params, cfg, tokens, vision_embeds)
     b, s = x.shape[0], x.shape[1]
@@ -196,11 +198,16 @@ def prefill(params, cfg, tokens, max_seq, vision_embeds=None, positions=None,
     return lm_logits(params, cfg, x, constrain), caches, aux_total
 
 
+@partial(jax.jit, static_argnames=("cfg", "constrain"),
+         donate_argnames=("caches",))
 def decode_step(params, cfg, tokens, pos, caches, constrain=_noop):
     """One decode step.
 
     tokens: (B,) int32 (or (B,K) for multi-codebook); pos: scalar int32
     absolute position of this token. Returns (logits (B, V...), caches).
+    Compiled once per (cfg, shapes, constrain) and cached across calls;
+    ``caches`` is donated, so the stacked cache is updated in place and
+    the value passed in must not be used again.
     """
     if cfg.n_codebooks > 1:
         x = embed_tokens(params, cfg, tokens[:, :, None])  # (B,1,d)

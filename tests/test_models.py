@@ -8,28 +8,36 @@ import pytest
 from repro.configs import get_tiny_config, list_architectures
 from repro.models import attention, rglru, transformer as T
 from repro.models.common import apply_mrope, apply_rope
+from repro.telemetry import recording
 
 
 def _f32(name, **kw):
     return get_tiny_config(name).replace(dtype="float32", **kw)
 
 
-@pytest.mark.parametrize("name", list_architectures())
-def test_decode_matches_forward(name):
-    # MoE archs use a generous capacity factor so no tokens drop (drops are
-    # count-dependent and legitimately differ between prefill and decode)
+def _decode_case(name, b=2, s=12):
+    """(cfg, params, tokens of s + 1 steps, vision embeds, position offset)
+    for a float32 tiny config.  MoE archs use a generous capacity factor so
+    no tokens drop (drops are count-dependent and legitimately differ
+    between prefill and decode)."""
     kw = {"capacity_factor": 8.0} if "moe" in get_tiny_config(name).arch_type \
         else {}
     cfg = _f32(name, **kw)
     key = jax.random.PRNGKey(1)
     params = T.init(key, cfg)
-    b, s = 2, 12
     shape = (b, cfg.n_codebooks, s + 1) if cfg.n_codebooks > 1 else (b, s + 1)
     tokens = jax.random.randint(key, shape, 0, cfg.vocab_size)
     ve, offset = None, 0
     if cfg.vision_tokens:
         ve = 0.02 * jax.random.normal(key, (b, cfg.vision_tokens, cfg.d_model))
         offset = cfg.vision_tokens
+    return cfg, params, tokens, ve, offset
+
+
+@pytest.mark.parametrize("name", list_architectures())
+def test_decode_matches_forward(name):
+    s = 12
+    cfg, params, tokens, ve, offset = _decode_case(name, s=s)
     pre = tokens[..., :s]
     new = tokens[..., s]
     full, _ = T.forward(params, cfg, tokens, vision_embeds=ve)
@@ -39,6 +47,74 @@ def test_decode_matches_forward(name):
     rel = float(jnp.max(jnp.abs(dec - want))) / (
         float(jnp.max(jnp.abs(want))) + 1e-9)
     assert rel < 5e-4, rel
+
+
+def _greedy_continuation(params, cfg, prompt, ve, offset, n_steps):
+    """Prefill, then ``n_steps`` decode steps fed their own greedy tokens,
+    each step handed the caches the previous one returned (and donated).
+    Returns the logits of every step and the greedy tokens."""
+    s = prompt.shape[-1]
+    logits, caches, _ = T.prefill(params, cfg, prompt, max_seq=32,
+                                  vision_embeds=ve)
+    logits = logits[:, -1]
+    outs = [logits]
+    for step in range(n_steps):
+        cur = jnp.argmax(logits, -1)
+        logits, caches = T.decode_step(params, cfg, cur,
+                                       jnp.int32(s + offset + step), caches)
+        outs.append(logits)
+    return ([np.asarray(x) for x in outs],
+            [np.asarray(jnp.argmax(x, -1)) for x in outs])
+
+
+@pytest.mark.parametrize("name", list_architectures())
+def test_compiled_decode_matches_eager(name):
+    """``prefill`` and ``decode_step`` are cached compiled programs (caches
+    donated); three chained steps give the eager path's greedy tokens and
+    logits, and a second continuation of the same shapes compiles
+    nothing."""
+    s = 12
+    cfg, params, tokens, ve, offset = _decode_case(name, s=s)
+    prompt = tokens[..., :s]
+    logits, toks = _greedy_continuation(params, cfg, prompt, ve, offset, 3)
+    with jax.disable_jit():
+        want_logits, want_toks = _greedy_continuation(params, cfg, prompt,
+                                                      ve, offset, 3)
+    for got, want in zip(toks, want_toks):
+        np.testing.assert_array_equal(got, want)
+    for got, want in zip(logits, want_logits):
+        # float32 with fusion reordering: a few ulps of the largest logit
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=1e-5 * np.abs(want).max())
+    with recording(True) as rec:
+        _greedy_continuation(params, cfg, prompt, ve, offset, 3)
+    assert rec.total_compiles == 0
+
+
+def test_decode_step_nested_under_outer_jit():
+    """``launch/steps.make_decode_step`` calls the compiled ``decode_step``
+    inside its caller's ``jit`` with a sharding ``constrain``: it lowers
+    with the caches donated and gives the direct call's logits."""
+    from repro.distributed.sharding import ShardingRules
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.steps import make_decode_step
+
+    s = 12
+    cfg, params, tokens, _, _ = _decode_case("llama3-8b", s=s)
+    rules = ShardingRules(cfg, make_host_mesh(), mode="serve")
+    step = jax.jit(make_decode_step(cfg, constrain=rules.constrain),
+                   donate_argnums=(3,))
+    new, pos = tokens[..., s], jnp.int32(s)
+    _, caches, _ = T.prefill(params, cfg, tokens[..., :s], max_seq=32)
+    want, _ = T.decode_step(params, cfg, new, pos, caches)
+    _, caches, _ = T.prefill(params, cfg, tokens[..., :s], max_seq=32)
+    hlo = step.lower(params, new, pos, caches).as_text()
+    assert "sharding" in hlo                  # the constraints are there
+    got, caches = step(params, new, pos, caches)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+    got2, _ = step(params, jnp.argmax(got, -1), pos + 1, caches)
+    assert bool(jnp.isfinite(got2).all())
 
 
 @pytest.mark.parametrize("name", ["llama3-8b", "gemma3-12b", "mixtral-8x22b"])

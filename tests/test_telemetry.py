@@ -403,6 +403,23 @@ def test_serve_round_carries_first_tokens_and_decode_phases(steps):
     cluster.stop(drain=False)
 
 
+def test_second_serve_round_compiles_no_prefill_or_decode():
+    """The edge decode continuation runs as cached compiled programs: a
+    second round of the same shapes compiles nothing in its prefill or
+    decode steps."""
+    bus = TelemetryBus()
+    # a prompt length no other test serves: the first round compiles
+    cluster, ids, clock, tokens = _serve_cluster(bus, seq=13)
+    for _ in range(2):
+        cluster.serve_round(tokens, decode_steps=3)
+    first, second = (e.fields for e in bus.snapshot("serve_round")[-2:])
+    assert first["prefill_compiles"] >= 1
+    assert second["decode_steps"] == first["decode_steps"] == len(ids) * 2
+    assert second["prefill_compiles"] == 0
+    assert second["decode_compiles"] == 0
+    cluster.stop(drain=False)
+
+
 # spans and the bus fields that report them: (span name, event, field)
 SPAN_FIELDS = [
     ("era.round.ingest", "admission_round", "ingest_s"),
